@@ -663,14 +663,11 @@ def main():
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
-    try:  # persistent XLA cache (same as tests/conftest.py): jit compiles
-        # are excluded from timed regions via warmups, so this only trims
-        # benchmark start-up, locally and in the CI bench/nightly jobs
-        import jax
-        jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:  # pragma: no cover - jax absent or too old
-        pass
+    # persistent XLA cache (same as tests/conftest.py): jit compiles are
+    # excluded from timed regions via warmups, so this only trims benchmark
+    # start-up, locally and in the CI bench/nightly jobs
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
     benches = {
         "table1": bench_table1_matvec,
         "table2": bench_table2_conv,

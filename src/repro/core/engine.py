@@ -578,8 +578,6 @@ def _build_jax_runner_faulty(cp: CompiledProgram):
             new = jnp.where(mask, out, old)
             fail = bern(k, p_switch, (W, R1))
             new = (old & fail) | (new & ~fail)
-            new = (new | jnp.take(sa1, x["dst"], axis=0)) \
-                & ~jnp.take(sa0, x["dst"], axis=0)
             return buf.at[x["dst"]].set(new)
 
         def row_step(buf, k, x):
@@ -592,8 +590,6 @@ def _build_jax_runner_faulty(cp: CompiledProgram):
             new = jnp.where(mask, out, old)
             fail = bern(k, p_switch, (W, C1))
             new = (old & fail) | (new & ~fail)
-            new = (new | jnp.take(sa1, x["dst"], axis=1).T) \
-                & ~jnp.take(sa0, x["dst"], axis=1).T
             return buf.at[:, x["dst"]].set(new.T)
 
         def init_step(buf, k, x):
@@ -603,7 +599,6 @@ def _build_jax_runner_faulty(cp: CompiledProgram):
                     & row_masks[x["init_r"][i]][None, :]
                 word = jnp.where(x["init_v"][i] > 0, dt(0xFFFFFFFF), dt(0))
                 val = word ^ bern(ks[i], p_init, (C1, R1))
-                val = (val | sa1) & ~sa0
                 buf = jnp.where(region, val, buf)
             return buf
 
@@ -612,6 +607,11 @@ def _build_jax_runner_faulty(cp: CompiledProgram):
             key, sub = jax.random.split(key)
             buf = lax.switch(x["mode"], (col_step, row_step, init_step),
                              buf, sub, x)
+            # stuck cells hold their value through every write. Applied to
+            # the whole word, not gathered per written line: on TPU the
+            # per-line form (take of sa0/sa1 at the written lines, then
+            # OR/AND-NOT) gave wrong words even with all-zero maps.
+            buf = (buf | sa1) & ~sa0
             return (buf, key), None
 
         (buf, _), _ = lax.scan(step, (buf0, key), xs, unroll=4)
@@ -731,6 +731,12 @@ def execute(
     Mosaic on TPU — and transparently falls back to jax/numpy for
     ineligible programs or fault runs (``backend`` field
     ``"pallas:fallback-<base>"``).
+
+    ``mesh`` (or an ambient ``distributed.sharding.use_mesh``) shards a
+    fault-free jax batch over the mesh's ``tiles`` axis (label suffix
+    ``+mesh<D>``). When a multi-device mesh is given and the run cannot
+    shard, it runs on one device and a
+    :class:`~repro.distributed.mesh_exec.MeshDeclinedWarning` says why.
     """
     t0 = time.perf_counter()
     if mesh is None:
@@ -755,6 +761,16 @@ def execute(
     return res
 
 
+def _mesh_declined(devices: int, reason: str) -> None:
+    """Tell the caller that a multi-device mesh ran on one device."""
+    import warnings
+
+    from ..distributed.mesh_exec import MeshDeclinedWarning
+    _metrics.counter("engine.sharded.declined").inc()
+    warnings.warn(f"mesh of {devices} devices declined: {reason}; running "
+                  f"on one device", MeshDeclinedWarning, stacklevel=4)
+
+
 def _execute_impl(
     cp: CompiledProgram,
     mem: np.ndarray,
@@ -776,13 +792,19 @@ def _execute_impl(
 
     # device topology the batch could shard over: >1 only when the mesh has
     # a usable 'tiles' axis, the batch fills it, and the run is fault-free
-    # (fault realizations stay on the audited single-device paths)
-    topo = 1
-    if mesh is not None and faults is None and have_jax():
+    # (fault realizations stay on the audited single-device paths). A
+    # multi-device mesh that ends up on one device says why (``declined``).
+    topo, mesh_size, declined = 1, 1, None
+    if mesh is not None and have_jax():
         from ..distributed.mesh_exec import mesh_devices
-        D = mesh_devices(mesh)
-        if D > 1 and mem.shape[0] >= D:
-            topo = D
+        mesh_size = mesh_devices(mesh)
+        if mesh_size > 1:
+            if faults is not None:
+                declined = "fault runs stay on one device"
+            elif mem.shape[0] < mesh_size:
+                declined = f"batch of {mem.shape[0]} is smaller than the mesh"
+            else:
+                topo = mesh_size
 
     base, variant = parse_backend(backend)
     label = backend
@@ -798,6 +820,8 @@ def _execute_impl(
     elif base == "pallas":
         from .pallas_exec import pallas_eligible, run_pallas
         if pallas_eligible(cp, faults):
+            if topo > 1:
+                _mesh_declined(topo, "the pallas backend runs on one device")
             out = run_pallas(cp, mem)
             if squeeze:
                 out = out[0]
@@ -844,7 +868,9 @@ def _execute_impl(
             f"FaultRealization batch {faults.batch} != memory batch {B}; "
             f"sample the realization for the batch it will run under")
 
-    if topo > 1 and base == "jax" and faults is None:
+    if topo > 1 and base != "jax":
+        declined = f"the {base} backend does not shard"
+    elif topo > 1:
         from ..distributed.mesh_exec import try_run_sharded
         sharded = try_run_sharded(cp, mem, variant, mesh)
         if sharded is not None:
@@ -854,6 +880,9 @@ def _execute_impl(
             return EngineResult(mem=out, cycles=cp.n_cycles,
                                 stats=dict(cp.stats),
                                 backend=f"{label}+mesh{D}", faults=faults)
+        declined = "the tiles axis does not shard this batch"
+    if declined is not None:
+        _mesh_declined(mesh_size, declined)
 
     rng = as_rng(rng) if isinstance(faults, FaultModel) else None
     chunks = []

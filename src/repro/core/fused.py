@@ -319,18 +319,19 @@ def _build_jax_fused(cp: CompiledProgram,
         """Masked scatter of one cycle's outputs, optional fault injection.
 
         ``out``/``mask`` are (n, L) in col mode and (C1, n) in row mode;
-        ``fail`` likewise (or None); ``sa=(sa0, sa1)`` or None.
+        ``fail`` likewise (or None); ``sa=(sa0, sa1)`` or None. Stuck cells
+        are re-imposed on the whole word after the write (the invariant
+        holds everywhere else already): on TPU, gathering the stuck-at maps
+        at the written lines gave wrong words even with all-zero maps.
         """
         old = buf[dst] if axis == 0 else buf[:, dst]
         new = jnp.where(mask, out, old)
         if fail is not None:
             new = (old & fail) | (new & ~fail)
+        buf = buf.at[dst].set(new) if axis == 0 else buf.at[:, dst].set(new)
         if sa is not None:
-            sa0, sa1 = sa
-            s0 = sa0[dst] if axis == 0 else sa0[:, dst]
-            s1 = sa1[dst] if axis == 0 else sa1[:, dst]
-            new = (new | s1) & ~s0
-        return buf.at[dst].set(new) if axis == 0 else buf.at[:, dst].set(new)
+            buf = (buf | sa[1]) & ~sa[0]
+        return buf
 
     def eval_static(buf, axis, gates, ins):
         """Gate-run-specialized evaluation with static gate structure."""

@@ -10,14 +10,15 @@ binary matvec      ``binary_matmul``              always (int32 popcount
 (±1 XNOR-popcount)  (XNOR + popcount reduction)    reduction is exact)
 encoded matvec     ``splitk_matvec``              ``n·(2^N−1)² < 2^24``
 (N-bit, mod 2^2N)   (split-K f32 accumulate)       (f32-exact integer range)
-valid conv         ``conv2d_shift``               ``k²·(2^N−1)² < 2^24``;
+valid conv         ``conv2d_shift_tiled``         ``k²·(2^N−1)² < 2^24``;
 (N-bit, mod 2^N)    (static tap-shift windows)     K known or stored in-array
 =================  =============================  ==========================
 
 The bridge works at the *plan* level: algorithm plans attach a
 ``pallas_spec`` (layout manifest) to the traces they compile, the backend
 extracts operand bits from the INITIAL memory image through that layout,
-computes with the kernels (interpret-mode off TPU, Mosaic on TPU), and
+computes with the kernels (interpret mode off TPU, Mosaic on TPU; see
+``kernels.ops.interpret_mode``), and
 writes only the plan's result field into an otherwise-zero image. Cycle and
 stat accounting still come from the compiled trace — the backend changes
 how fast the simulation runs, never what the simulated machine would cost.
@@ -38,6 +39,11 @@ Arithmetic bridges (why the results are *exactly* equal, not close):
 * encoded matvec / conv — f32 accumulation of non-negative integers is
   exact below 2^24 (the mantissa width); eligibility enforces the bound on
   the *true* sum, the modulus is applied on the host afterwards.
+
+Kernel shapes: every operand is zero-padded to blocks Mosaic accepts (the
+(8, 128) tiling, or the whole array), so each shape a spec can carry
+compiles for the chip; :func:`kernel_call` lists them and the v5e compile
+tests (``tests/test_tpu_compile.py``) compile them for the paper's plans.
 
 Ineligible traces (no spec, fault injection requested, bound exceeded, jax
 absent) never error — ``engine.execute`` falls back to the best concrete
@@ -117,7 +123,13 @@ def conv_spec(plan) -> Optional[dict]:
 
 
 def pallas_eligible(cp, faults=None) -> bool:
-    """Can ``cp`` run on the pallas backend bit-identically?"""
+    """Can ``cp`` run on the pallas backend bit-identically?
+
+    Shape is never the reason to refuse: :func:`kernel_call` pads every
+    operand to a block shape Mosaic lowers. What is refused is a trace
+    without a spec, a fault run, and a sum the f32 accumulator cannot hold
+    exactly.
+    """
     spec = getattr(cp, "pallas_spec", None)
     if spec is None or faults is not None:
         return False
@@ -143,16 +155,27 @@ def _pad_to(v: int, mult: int) -> int:
     return v if v % mult == 0 else (v // mult + 1) * mult
 
 
+def _packed_width(n: int) -> int:
+    """Packed words ``Kw`` for an ``n``-element operand: ``ceil(n/32)``,
+    padded to a multiple of ``binary_matmul``'s k block when it spans more
+    than one."""
+    from ..kernels.binary_matmul import DEFAULT_BK
+
+    words = -(-n // 32)
+    return _pad_to(words, DEFAULT_BK) if words > DEFAULT_BK else words
+
+
 def _pack_words(bits: np.ndarray) -> np.ndarray:
     """(…, n) {0,1} → (…, Kw) uint32, little-endian bit order, zero-padded
-    so ``Kw`` meets ``binary_matmul``'s block constraint (Kw ≤ 8 or 8|Kw).
+    to :func:`_packed_width` so ``binary_matmul``'s k block is the whole
+    ``Kw`` or a multiple of 128 words.
 
     Same word convention as the engine's canonical packed layout
     (``engine.WORD_BITS`` = 32, bit ``b`` of word ``w`` = element
     ``32w + b``), just packed along the operand axis instead of the batch.
     """
     n = bits.shape[-1]
-    words = _pad_to(max(1, -(-n // 32)), 8) if n > 256 else -(-n // 32)
+    words = _packed_width(n)
     pad = words * 32 - n
     if pad:
         z = np.zeros(bits.shape[:-1] + (pad,), dtype=bits.dtype)
@@ -168,9 +191,44 @@ def _write_field(mem: np.ndarray, rows: int, cols: np.ndarray,
     mem[:rows, cols] = encode_uint(values, len(cols))
 
 
-def _on_tpu() -> bool:
-    import jax
-    return jax.default_backend() == "tpu"
+def _kernel_shapes(spec) -> tuple:
+    """Padded operand shapes of the kernel call :func:`run_pallas` makes for
+    one instance of ``spec``: every block is a whole array or a multiple of
+    the (8, 128) tiling, which is what Mosaic lowers."""
+    kind, m, n = spec["kind"], spec["m"], spec["n"]
+    if kind == "binary_matvec":                        # binary_matmul
+        kw = _packed_width(n)
+        return (_pad_to(m, 128) if m > 128 else m, kw), (1, kw)
+    if kind == "matvec":                               # splitk_matvec
+        kcols = _pad_to(n, 512) if n > 512 else n
+        return (_pad_to(m, 256) if m > 256 else m, kcols), (kcols,)
+    k = spec["k"]                                      # conv2d_shift_tiled
+    oh, ow = (_pad_to(v, 128) if v > 128 else v
+              for v in (m - k + 1, n - k + 1))
+    return (oh + k - 1, ow + k - 1), (k, k)
+
+
+def kernel_call(spec) -> tuple:
+    """``(kernel, ((shape, dtype), ...))``: the Pallas kernel and operand
+    shapes :func:`run_pallas` passes per instance of ``spec``."""
+    import jax.numpy as jnp
+
+    from ..kernels.binary_matmul import binary_matmul
+    from ..kernels.conv2d_shift import conv2d_shift_tiled
+    from ..kernels.splitk_matvec import splitk_matvec
+
+    kind = spec["kind"]
+    fn = {"binary_matvec": binary_matmul, "matvec": splitk_matvec,
+          "conv": conv2d_shift_tiled}[kind]
+    dt = jnp.uint32 if kind == "binary_matvec" else jnp.float32
+    return fn, tuple((shape, dt) for shape in _kernel_shapes(spec))
+
+
+def _padded(a: np.ndarray, shape: tuple, dtype) -> np.ndarray:
+    """``a`` zero-padded at the end of each axis to ``shape``."""
+    out = np.zeros(shape, dtype)
+    out[tuple(slice(0, d) for d in a.shape)] = a
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -187,14 +245,11 @@ def _run_binary_matvec(spec, mems: np.ndarray, interpret: bool) -> np.ndarray:
     a_packed = _pack_words(a_bits)                     # (B, m, Kw)
     x_packed = _pack_words(x_bits)[:, None, :]         # (B, 1, Kw)
     kpad = a_packed.shape[-1] * 32
-    mrows = _pad_to(m, 128) if m > 128 else m
+    a_shape, _ = _kernel_shapes(spec)
 
     out = np.zeros_like(mems)
     for b in range(mems.shape[0]):
-        ap = a_packed[b]
-        if mrows != m:
-            ap = np.concatenate(
-                [ap, np.zeros((mrows - m, ap.shape[1]), np.uint32)])
+        ap = _padded(a_packed[b], a_shape, np.uint32)
         dot = np.asarray(binary_matmul(ap, x_packed[b],
                                        interpret=interpret))[:m, 0]
         mism = (kpad - dot.astype(np.int64)) // 2      # pad bits all match
@@ -227,14 +282,11 @@ def _run_matvec(spec, mems: np.ndarray, interpret: bool) -> np.ndarray:
         x[:, i * nb:(i + 1) * nb] = decode_uint(
             mems[:, i * m][:, spec["x_cols"]].reshape(B, nb, N))
 
-    mrows = _pad_to(m, 256) if m > 256 else m
-    kcols = _pad_to(n, 512) if n > 512 else n
+    a_shape, x_shape = _kernel_shapes(spec)
     out = np.zeros_like(mems)
     for b in range(B):
-        af = np.zeros((mrows, kcols), dtype=np.float32)
-        af[:m, :n] = A[b]
-        xf = np.zeros((kcols,), dtype=np.float32)
-        xf[:n] = x[b]
+        af = _padded(A[b], a_shape, np.float32)
+        xf = _padded(x[b], x_shape, np.float32)
         y = np.asarray(splitk_matvec(af, xf, interpret=interpret))[:m]
         y = np.rint(y).astype(np.int64) % (1 << W)     # exact (< 2^24)
         _write_field(out[b], m, spec["acc_cols"], y)
@@ -242,7 +294,7 @@ def _run_matvec(spec, mems: np.ndarray, interpret: bool) -> np.ndarray:
 
 
 def _run_conv(spec, mems: np.ndarray, interpret: bool) -> np.ndarray:
-    from ..kernels.conv2d_shift import conv2d_shift
+    from ..kernels.conv2d_shift import conv2d_shift_tiled
 
     m, n, k, N = spec["m"], spec["n"], spec["k"], spec["N"]
     alpha, nb, nin, mpad = (spec["alpha"], spec["nb"], spec["nin"],
@@ -269,11 +321,13 @@ def _run_conv(spec, mems: np.ndarray, interpret: bool) -> np.ndarray:
         kb = mems[:, beta % m, spec["kstore"][beta // m]]    # (B, k²·N)
         Ks = decode_uint(kb.reshape(B, k * k, N)).reshape(B, k, k)
 
+    a_shape, _ = _kernel_shapes(spec)
     out = np.zeros_like(mems)
     for b in range(B):
-        o = np.asarray(conv2d_shift(A[b].astype(np.float32),
-                                    Ks[b].astype(np.float32),
-                                    interpret=interpret))
+        o = np.asarray(conv2d_shift_tiled(_padded(A[b], a_shape, np.float32),
+                                          Ks[b].astype(np.float32),
+                                          interpret=interpret))
+        o = o[:m - k + 1, :n - k + 1]                  # crop the padding
         o = np.rint(o).astype(np.int64) % (1 << N)     # exact (< 2^24)
         for i in range(alpha):
             lo = i * mpad
@@ -300,12 +354,14 @@ def run_pallas(cp, mems: np.ndarray) -> np.ndarray:
     image per the result contract above (result field populated, scratch
     zero). Caller (``engine.execute``) checks :func:`pallas_eligible` first.
     """
+    from ..kernels.ops import interpret_mode
+
     spec = cp.pallas_spec
     mems = np.ascontiguousarray(mems, dtype=np.uint8)
-    return _RUNNERS[spec["kind"]](spec, mems, interpret=not _on_tpu())
+    return _RUNNERS[spec["kind"]](spec, mems, interpret=interpret_mode())
 
 
 __all__ = [
-    "binary_matvec_spec", "conv_spec", "matvec_spec", "pallas_eligible",
-    "run_pallas",
+    "binary_matvec_spec", "conv_spec", "kernel_call", "matvec_spec",
+    "pallas_eligible", "run_pallas",
 ]

@@ -13,10 +13,12 @@ single-device executors (integer/bitwise ops have no reassociation freedom).
 Placement goes through the dormant logical-axis machinery in
 :mod:`repro.distributed.sharding`: the stacked chunk buffer's leading axis
 is the logical ``"tiles"`` axis, resolved against the active mesh by
-:func:`~repro.distributed.sharding.resolve_spec`. When the resolution drops
-the axis (no ``tiles`` mesh axis, or an indivisible chunk count) the caller
-falls back to the ordinary single-device chunk loop — fallback is a
-placement decision, not a separate code path.
+:func:`~repro.distributed.sharding.resolve_spec`. When a multi-device mesh
+is asked for and the run cannot shard (a fault run, a batch smaller than
+the mesh, a non-jax backend, or a resolution that drops the axis), the
+engine runs the ordinary single-device chunk loop and says so: a
+:class:`MeshDeclinedWarning` naming the reason, and the
+``engine.sharded.declined`` counter.
 
 Chunking: a batch of B crossbars becomes S word-packed chunks, S a multiple
 of the device count with per-chunk widths balanced to ``ceil(B/S)`` — e.g.
@@ -48,6 +50,10 @@ TILE_AXIS = "tiles"
 # widest packed chunk the sharded path emits (one canonical uint32 word,
 # == engine.WORD_BITS)
 MAX_CHUNK = 32
+
+
+class MeshDeclinedWarning(RuntimeWarning):
+    """A multi-device mesh was asked for and the run went to one device."""
 
 
 def tile_mesh(n: Optional[int] = None):
@@ -93,7 +99,6 @@ def _sharded_runner(cp, mesh, variant: str, spec):
     if fn is not None:
         return fn
     import jax
-    from jax.experimental.shard_map import shard_map
 
     if variant == "fused":
         from ..core.fused import jax_fused_body
@@ -101,8 +106,8 @@ def _sharded_runner(cp, mesh, variant: str, spec):
     else:
         from ..core.engine import jax_unfused_body
         body = jax_unfused_body(cp)
-    fn = jax.jit(shard_map(jax.vmap(body), mesh=mesh, in_specs=(spec,),
-                           out_specs=spec, check_rep=False))
+    fn = jax.jit(jax.shard_map(jax.vmap(body), mesh=mesh, in_specs=(spec,),
+                               out_specs=spec, check_vma=False))
     cp._caches[key] = fn
     return fn
 
@@ -113,8 +118,9 @@ def try_run_sharded(cp, mem: np.ndarray, variant: str, mesh
 
     Returns ``(out_mem, devices, n_chunks)``, or ``None`` when the mesh
     placement does not apply (no ``tiles`` axis, one device, B < devices, or
-    ``resolve_spec`` replicates the chunk axis) — the engine then falls back
-    to its single-device chunk loop, bit-identically.
+    ``resolve_spec`` replicates the chunk axis) — the engine then runs its
+    single-device chunk loop, bit-identically, and warns
+    (:class:`MeshDeclinedWarning`) where the mesh had several devices.
     """
     from ..core.engine import _pack, _unpack
     from .sharding import resolve_spec
@@ -149,5 +155,5 @@ def try_run_sharded(cp, mem: np.ndarray, variant: str, mesh
     return res, D, len(widths)
 
 
-__all__ = ["MAX_CHUNK", "TILE_AXIS", "chunk_widths", "mesh_devices",
-           "tile_mesh", "try_run_sharded"]
+__all__ = ["MAX_CHUNK", "MeshDeclinedWarning", "TILE_AXIS", "chunk_widths",
+           "mesh_devices", "tile_mesh", "try_run_sharded"]

@@ -15,7 +15,9 @@ On TPU the same structure becomes:
 C[i,j] = Σ_k a[i,k]·b[j,k], a,b ∈ {−1,+1}  =  K − 2·popcount(a_bits ^ b_bits).
 
 Block sizes are MXU/VPU aligned (multiples of (8,128) for the output tile);
-VMEM working set = bm·bk + bn·bk + bm·bn words.
+VMEM working set = bm·bk + bn·bk + bm·bn words. ``B`` enters the kernel
+transposed, so each packed word of the k block is a static column of the
+``a`` block and a static row of the ``b`` block.
 """
 from __future__ import annotations
 
@@ -28,11 +30,14 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
-DEFAULT_BK = 8  # packed words (= 256 unpacked elements) per grid step
+# packed words per k step. The k block is the lane axis of the ``a`` block,
+# so Mosaic takes it only as the full ``Kw`` or a multiple of 128: operands
+# up to 128 words (4096 elements) run in one k step, wider ones must be
+# padded to a multiple of 128 words.
+DEFAULT_BK = 128
 
 
-def _binary_matmul_kernel(a_ref, b_ref, o_ref, *, k_words: int, K: int,
-                          nsteps: int):
+def _binary_matmul_kernel(a_ref, bt_ref, o_ref, *, K: int, nsteps: int):
     """Grid = (M/bm, N/bn, K'/bk); accumulate popcounts over the k axis."""
     kk = pl.program_id(2)
 
@@ -40,17 +45,19 @@ def _binary_matmul_kernel(a_ref, b_ref, o_ref, *, k_words: int, K: int,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = a_ref[...]  # (bm, bk) uint32
-    b = b_ref[...]  # (bn, bk) uint32
+    a = a_ref[...]    # (bm, bk) uint32
+    bt = bt_ref[...]  # (bk, bn) uint32, B transposed
 
     # XNOR-popcount: mismatches per word, summed over the block's words.
-    # One word at a time keeps the VMEM footprint at bm*bn (the MatPIM
-    # "serial within partition, parallel across partitions" shape).
-    def body(w, acc):
-        x = a[:, w][:, None] ^ b[:, w][None, :]        # (bm, bn) uint32
-        return acc + jnp.bitwise_count(x).astype(jnp.int32)
-
-    mism = jax.lax.fori_loop(0, a.shape[1], body, jnp.zeros(o_ref.shape, jnp.int32))
+    # One word at a time keeps the working set at bm*bn (the MatPIM
+    # "serial within partition, parallel across partitions" shape). The
+    # word loop is unrolled with static slices: a column of ``a`` against a
+    # row of ``bt`` broadcasts to (bm, bn), and Mosaic has no lowering for
+    # a lane slice at a traced index.
+    mism = jnp.zeros(o_ref.shape, jnp.int32)
+    for w in range(a.shape[1]):
+        x = a[:, w:w + 1] ^ bt[w:w + 1, :]                 # (bm, bn) uint32
+        mism += jnp.bitwise_count(x).astype(jnp.int32)
     o_ref[...] += mism
 
     # last k-step: convert accumulated mismatch count to the ±1 dot product
@@ -73,13 +80,13 @@ def binary_matmul(a_packed: jnp.ndarray, b_packed: jnp.ndarray,
     nsteps = Kw // bk
     grid = (M // bm, N // bn, nsteps)
     return pl.pallas_call(
-        functools.partial(_binary_matmul_kernel, k_words=bk, K=K, nsteps=nsteps),
+        functools.partial(_binary_matmul_kernel, K=K, nsteps=nsteps),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
+            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         interpret=interpret,
-    )(a_packed, b_packed)
+    )(a_packed, b_packed.T)

@@ -10,8 +10,8 @@ Mosaic fuses them into the VPU/MXU pipeline.
 
 Two variants:
 * ``conv2d_shift``       — whole image resident in VMEM (fine to ~4 MB);
-* ``conv2d_shift_tiled`` — output tiled on a grid, halo'd input loads via
-  dynamic slices from unblocked (ANY-space) input.
+* ``conv2d_shift_tiled`` — output tiled on a grid, each halo'd input tile
+  copied from the unblocked (ANY-space) input into VMEM scratch.
 """
 from __future__ import annotations
 
@@ -20,6 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _conv_kernel(a_ref, k_ref, o_ref, *, kh: int, kw: int):
@@ -45,12 +46,24 @@ def conv2d_shift(a: jnp.ndarray, k: jnp.ndarray,
     )(a, k)
 
 
-def _conv_tiled_kernel(a_ref, k_ref, o_ref, *, kh: int, kw: int,
-                       bh: int, bw: int):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    # halo'd input tile: (bh + kh - 1, bw + kw - 1) at element offset (i*bh, j*bw)
-    tile = pl.load(a_ref, (pl.ds(i * bh, bh + kh - 1), pl.ds(j * bw, bw + kw - 1)))
+def _round_up(v: int, mult: int) -> int:
+    return -(-v // mult) * mult
+
+
+def _conv_tiled_kernel(a_hbm, k_ref, o_ref, tile, sem, *, kh: int, kw: int,
+                       bh: int, bw: int, gh: int, gw: int):
+    # halo'd input tile at element offset (i*bh, j*bw), copied from the
+    # unblocked HBM input into VMEM scratch; the copy window is the scratch
+    # shape, (bh + kh - 1, bw + kw - 1) rounded up to the (8, 128) tiling.
+    # A one-block grid axis uses a static 0 offset: Mosaic must prove the
+    # offset tile-aligned, and a whole-extent block need not be aligned.
+    r0 = pl.program_id(0) * bh if gh > 1 else 0
+    c0 = pl.program_id(1) * bw if gw > 1 else 0
+    th, tw = tile.shape
+    copy = pltpu.make_async_copy(
+        a_hbm.at[pl.ds(r0, th), pl.ds(c0, tw)], tile, sem)
+    copy.start()
+    copy.wait()
     acc = jnp.zeros((bh, bw), jnp.float32)
     for v in range(kh):
         for h in range(kw):
@@ -62,9 +75,11 @@ def _conv_tiled_kernel(a_ref, k_ref, o_ref, *, kh: int, kw: int,
 @functools.partial(jax.jit, static_argnames=("bh", "bw", "interpret"))
 def conv2d_shift_tiled(a: jnp.ndarray, k: jnp.ndarray, bh: int = 128,
                        bw: int = 128, interpret: bool = False) -> jnp.ndarray:
-    """Valid conv with output tiling + halo'd dynamic-slice input loads.
+    """Valid conv with output tiling + halo'd input copies into VMEM.
 
-    Output must tile evenly (pad the input if needed).
+    Output must tile evenly (pad the input if needed). On TPU the output
+    block must also meet the (8, 128) tiling: ``bh`` a multiple of 8 or the
+    whole output height, ``bw`` a multiple of 128 or the whole width.
     """
     H, W = a.shape
     kh, kw = k.shape
@@ -72,15 +87,23 @@ def conv2d_shift_tiled(a: jnp.ndarray, k: jnp.ndarray, bh: int = 128,
     bh, bw = min(bh, OH), min(bw, OW)
     assert OH % bh == 0 and OW % bw == 0, "output must tile evenly"
     grid = (OH // bh, OW // bw)
+    # HBM copy windows must be whole (8, 128) tiles: zero-pad the input so
+    # the last window stays in bounds (the padding is never read into acc)
+    th, tw = _round_up(bh + kh - 1, 8), _round_up(bw + kw - 1, 128)
+    a = jnp.pad(a, ((0, (grid[0] - 1) * bh + th - H),
+                    (0, (grid[1] - 1) * bw + tw - W)))
     return pl.pallas_call(
-        functools.partial(_conv_tiled_kernel, kh=kh, kw=kw, bh=bh, bw=bw),
+        functools.partial(_conv_tiled_kernel, kh=kh, kw=kw, bh=bh, bw=bw,
+                          gh=grid[0], gw=grid[1]),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),   # manual halo loads
+            pl.BlockSpec(memory_space=pl.ANY),   # manual halo copies
             pl.BlockSpec((kh, kw), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bh, bw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((OH, OW), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((th, tw), a.dtype),
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
     )(a, k)
 

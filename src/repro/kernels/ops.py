@@ -1,11 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels (+ CPU fallbacks).
 
-On TPU the kernels compile to Mosaic and ``use_pallas`` defaults on. On CPU
-(this container) Pallas only *interprets* — far slower than the jnp ``ref``
-fallbacks — so the default follows :func:`_on_tpu` and dispatches to ``ref``
-off-TPU; pass ``use_pallas=True`` explicitly to force interpret-mode Pallas
-(the kernel test suites do). The wrappers are what models/ and the serving
-engine call.
+On TPU the kernels compile to Mosaic and ``use_pallas`` defaults on. On any
+other platform Pallas only *interprets* — far slower than the jnp ``ref``
+fallbacks — so the default follows :func:`interpret_mode` and dispatches to
+``ref`` off-TPU; pass ``use_pallas=True`` explicitly to force
+interpret-mode Pallas (the kernel test suites do). The wrappers are what
+models/ and the serving engine call.
 """
 from __future__ import annotations
 
@@ -23,8 +23,10 @@ from .splitk_matvec import splitk_matvec
 pack_bits = ref.pack_bits
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode: exactly when the
+    default platform is not a TPU. The one place that decides it."""
+    return jax.default_backend() != "tpu"
 
 
 def as_packed_words(w) -> jnp.ndarray:
@@ -76,9 +78,9 @@ def binary_dense(x: jnp.ndarray, w_packed: jnp.ndarray, K: int,
     xp = pack_bits(x2, axis=-1)
     w_packed = as_packed_words(w_packed)
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = not interpret_mode()
     if use_pallas:
-        y = binary_matmul(xp, w_packed, interpret=not _on_tpu())
+        y = binary_matmul(xp, w_packed, interpret=interpret_mode())
     else:
         y = ref.binary_matmul_packed_ref(xp, w_packed, K)
     return y.reshape(*lead, -1)
@@ -87,20 +89,20 @@ def binary_dense(x: jnp.ndarray, w_packed: jnp.ndarray, K: int,
 def matvec(a: jnp.ndarray, x: jnp.ndarray, use_pallas: bool | None = None
            ) -> jnp.ndarray:
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = not interpret_mode()
     if use_pallas:
-        return splitk_matvec(a, x, interpret=not _on_tpu())
+        return splitk_matvec(a, x, interpret=interpret_mode())
     return ref.splitk_matvec_ref(a, x)
 
 
 def conv2d(a: jnp.ndarray, k: jnp.ndarray, tiled: bool = False,
            use_pallas: bool | None = None) -> jnp.ndarray:
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = not interpret_mode()
     if not use_pallas:
         return ref.conv2d_shift_ref(a, k)
     fn = conv2d_shift_tiled if tiled else conv2d_shift
-    return fn(a, k, interpret=not _on_tpu())
+    return fn(a, k, interpret=interpret_mode())
 
 
 def conv2d_binary(a_packed: jnp.ndarray, k_packed: jnp.ndarray,
@@ -108,7 +110,7 @@ def conv2d_binary(a_packed: jnp.ndarray, k_packed: jnp.ndarray,
     a_packed = as_packed_words(a_packed)
     k_packed = as_packed_words(k_packed)
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = not interpret_mode()
     if use_pallas:
-        return binary_conv2d(a_packed, k_packed, interpret=not _on_tpu())
+        return binary_conv2d(a_packed, k_packed, interpret=interpret_mode())
     return ref.binary_conv2d_ref(a_packed, k_packed)

@@ -47,6 +47,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+import traceback
 from collections import OrderedDict
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -122,6 +123,9 @@ class CacheStats:
     async_compiles: int = 0   # misses compiled off-path by the worker pool
     store_hits: int = 0       # misses served from the persistent plan store
     prewarms: int = 0         # plans whose executor warm-up ran off-path
+    # off-path warm-ups that raised (the first batch then warms inline);
+    # the traceback of the latest is PlanService.last_prewarm_error
+    prewarm_errors: int = 0
 
     @property
     def hit_rate(self) -> float:
@@ -178,6 +182,21 @@ class _Pending:
     faults: object = None
     submitted_step: int = 0
     running: bool = False           # claimed by an in-flight bucket execute
+
+
+def _run_warm(warm: Callable[[], float], info: dict) -> None:
+    """Run an off-path executor warm-up into a compile job's ``info``.
+
+    A failed warm-up is not fatal (the plan's first batch warms inline), so
+    the job keeps going, but the failure is recorded: ``info["warm_error"]``
+    carries the traceback, which :meth:`PlanService._collect_landed` counts
+    in ``CacheStats.prewarm_errors``.
+    """
+    try:
+        info["warm_s"] = warm()
+        info["prewarmed"] = True
+    except Exception:
+        info["warm_error"] = traceback.format_exc()
 
 
 def _concat_realizations(reals: List[FaultRealization]) -> FaultRealization:
@@ -288,6 +307,7 @@ class PlanService:
         # never take it (job closures touch only wrapper + store), so
         # holding it while waiting on a job cannot deadlock.
         self._lock = threading.RLock()
+        self.last_prewarm_error: Optional[str] = None
 
     def close(self) -> None:
         """Shut down the compile pool; in-flight jobs finish first."""
@@ -397,11 +417,7 @@ class PlanService:
 
         def job():
             info = {"store_hit": False, "warm_s": 0.0, "prewarmed": False}
-            try:
-                info["warm_s"] = warm(plan.compile(fuse=fuse))
-                info["prewarmed"] = True
-            except Exception:
-                pass    # warm-up is an optimization; the first batch heals
+            _run_warm(lambda: warm(plan.compile(fuse=fuse)), info)
             return info
 
         job_h = self._pool.submit(key, job, block=False)
@@ -447,11 +463,7 @@ class PlanService:
                 # runners) off-path too, so the plan's first real batch runs
                 # at steady-state speed; warm failure is non-fatal — the
                 # first batch self-heals — unlike a compile failure above
-                try:
-                    info["warm_s"] = warm(cp)
-                    info["prewarmed"] = True
-                except Exception:
-                    pass
+                _run_warm(lambda: warm(cp), info)
             return info
 
         job_h = self._pool.submit(key, job, block=False)
@@ -489,6 +501,10 @@ class PlanService:
                     # synchronously as a self-healing fallback
                     raise job.error
                 info = job.result or {}
+                if info.get("warm_error"):
+                    self.stats.prewarm_errors += 1
+                    self.last_prewarm_error = info["warm_error"]
+                    _metrics.counter("serve.prewarm_errors").inc()
                 dt = job.wall_s - info.get("warm_s", 0.0)
                 self.stats.compile_s += dt
                 _metrics.counter("serve.compile_s").inc(dt)

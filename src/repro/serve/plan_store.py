@@ -3,12 +3,13 @@
 The serving layer's whole cold-start cost is re-deriving state that is a
 pure function of the plan key — lowering the program to a packed trace,
 fusing the macro-op schedule, and (on the jax path) XLA compilation. This
-module persists the first two as one ``.npz`` file per plan and points
-JAX's own persistent compilation cache at a sibling directory, so a
+module persists the first two as one ``.npz`` file per plan, so a
 restarted :class:`repro.serve.matpim.PlanService` built on the same store
 path serves its first mixed batch with **zero** ``compile_program`` calls
 (the restart round trip is asserted end-to-end in
-``tests/test_plan_store.py``).
+``tests/test_plan_store.py``). XLA executables persist in JAX's own
+compilation cache, which the process's entry point places
+(:mod:`repro.compile_cache`); a store never repoints it.
 
 Storage contract
 ----------------
@@ -71,36 +72,14 @@ def store_key(plan_key: object) -> str:
     return hashlib.sha256(repr(plan_key).encode()).hexdigest()[:32]
 
 
-def _point_jax_cache(path: Path) -> Optional[str]:
-    """Aim JAX's persistent compilation cache at ``path`` (best-effort)."""
-    try:
-        import jax
-
-        path.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(path))
-        try:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.5)
-        except Exception:
-            pass
-        return str(path)
-    except Exception:       # jax absent or too old: trace store still works
-        return None
-
-
 class PlanStore:
     """One directory of serialized compiled plans.
 
-    ``configure_jax_cache=True`` (the default) also points JAX's persistent
-    compilation cache at ``<path>/xla`` so jitted executables restart warm
-    alongside the traces; tests that must not disturb the process-wide jax
-    cache config pass ``False``. Load/put are thread-safe by construction
-    (independent files, unique tmp names) — the compile pool calls them
-    from worker threads without locks.
+    Load/put are thread-safe by construction (independent files, unique tmp
+    names) — the compile pool calls them from worker threads without locks.
     """
 
-    def __init__(self, path: os.PathLike,
-                 configure_jax_cache: bool = True):
+    def __init__(self, path: os.PathLike):
         self.path = Path(path)
         self.path.mkdir(parents=True, exist_ok=True)
         self.hits = 0
@@ -109,8 +88,6 @@ class PlanStore:
         self.puts = 0
         self.put_errors = 0
         self.last_error: Optional[str] = None
-        self.jax_cache_dir = (_point_jax_cache(self.path / "xla")
-                              if configure_jax_cache else None)
 
     # -- paths ---------------------------------------------------------------
 

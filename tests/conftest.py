@@ -40,13 +40,7 @@ def single_retry():
 
 # Persistent XLA compilation cache: the model-smoke/serve tests are dominated
 # by jit compiles, so repeat local runs and cache-restoring CI get much
-# faster. Harmless no-op if the jax version lacks the option.
-try:
-    import jax
+# faster. $JAX_COMPILATION_CACHE_DIR overrides the checkout's .jax_cache.
+from repro.compile_cache import configure_compile_cache  # noqa: E402
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(os.path.dirname(__file__), "..",
-                                   ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-except Exception:  # pragma: no cover - older jax
-    pass
+configure_compile_cache()

@@ -271,7 +271,7 @@ _CRASH_WRITER = textwrap.dedent("""
     sys.path.insert(0, sys.argv[2])
     from repro.core import BinaryMatvecPlan
     from repro.serve.plan_store import PlanStore
-    store = PlanStore(sys.argv[1], configure_jax_cache=False)
+    store = PlanStore(sys.argv[1])
     cp = BinaryMatvecPlan(8, 32, rows=64, cols=256, parts=8).compile()
     print("ready", flush=True)          # parent kills us after this line
     while True:
@@ -294,7 +294,7 @@ def test_sigkill_mid_store_write_leaves_store_loadable(tmp_path):
             proc.kill()
         proc.stdout.close()
 
-    survivor = PlanStore(store_path, configure_jax_cache=False)
+    survivor = PlanStore(store_path)
     cp = survivor.load(("victim",))
     # atomic tmp+rename: either the last COMPLETE entry, or a clean miss —
     # never a half-written file surfacing as corruption
@@ -312,7 +312,7 @@ def test_torn_write_without_rename_is_a_clean_miss(tmp_path):
     """Deterministic stand-in for the kill race: a writer that dies between
     tmp-write and rename leaves only a tmp file — the entry itself must
     read as a miss and the litter must not crash directory scans."""
-    store = PlanStore(tmp_path / "store", configure_jax_cache=False)
+    store = PlanStore(tmp_path / "store")
     (store.path / ".tmp-dead1234.npz").write_bytes(b"PK\x03\x04 torn")
     assert store.load(("never-renamed",)) is None
     assert store.misses == 1 and store.corrupt == 0

@@ -172,7 +172,7 @@ def test_ops_default_dispatches_to_ref_off_tpu(monkeypatch):
     ``use_pallas=True`` still forces the Pallas path."""
     from repro.kernels import ops
 
-    assert not ops._on_tpu()
+    assert ops.interpret_mode()
 
     def boom(*a, **k):
         raise AssertionError("Pallas path taken by default off-TPU")

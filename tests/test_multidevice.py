@@ -18,6 +18,7 @@ on every PR even when CI forgets the env.
 import os
 import subprocess
 import sys
+import warnings
 from pathlib import Path
 
 import numpy as np
@@ -26,7 +27,7 @@ import pytest
 from repro.core import have_jax
 from repro.core.tiling import TiledBinaryMatvec, TiledConv2d, TiledMatvec
 from repro.device.faults import FaultModel, FaultRealization
-from repro.distributed.mesh_exec import chunk_widths
+from repro.distributed.mesh_exec import MeshDeclinedWarning, chunk_widths
 from repro.serve.matpim import PlanService, ServeRequest
 
 GEOM = dict(rows=64, cols=256, parts=8)
@@ -68,8 +69,9 @@ def test_chunk_widths_rejects_underfilled_mesh():
 
 @needs_jax
 def test_single_device_mesh_is_a_no_op():
-    """On a 1-device mesh the sharded path declines and the engine falls
-    back — the single-device contract of the acceptance criteria."""
+    """On a 1-device mesh the sharded path declines and the engine runs on
+    its one device — the single-device contract — with no decline warning
+    (there is nothing to shard)."""
     from repro.distributed.mesh_exec import mesh_devices, tile_mesh, \
         try_run_sharded
 
@@ -83,7 +85,9 @@ def test_single_device_mesh_is_a_no_op():
     A = rng.choice([-1, 1], size=(64, 416))
     x = rng.choice([-1, 1], size=416)
     y0, r0 = t.run(A, x, backend="jax")
-    y1, r1 = t.run(A, x, backend="jax", mesh=mesh)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", MeshDeclinedWarning)
+        y1, r1 = t.run(A, x, backend="jax", mesh=mesh)
     assert "+mesh" not in r1.backend
     np.testing.assert_array_equal(y0, y1)
 
@@ -156,7 +160,8 @@ def test_batch_smaller_than_mesh_falls_back():
     A = rng.choice([-1, 1], size=(64, 416))
     x = rng.choice([-1, 1], size=416)
     y0, _ = t.run(A, x, backend="jax")
-    y1, r1 = t.run(A, x, backend="jax", mesh=tile_mesh(8))
+    with pytest.warns(MeshDeclinedWarning, match="smaller than the mesh"):
+        y1, r1 = t.run(A, x, backend="jax", mesh=tile_mesh(8))
     assert "+mesh" not in r1.backend
     np.testing.assert_array_equal(y0, y1)
 
@@ -173,14 +178,16 @@ def test_fixed_fault_realization_masks_identical_under_mesh():
         FaultModel(p_sa0=0.002, p_sa1=0.001), t.n_tiles, t.plan.rows,
         t.plan.cols, cp.n_cycles, cp.W, cp.I, rng=42)
     y0, r0 = t.run(A, x, backend="jax", faults=real)
-    y1, r1 = t.run(A, x, backend="jax", faults=real, mesh=tile_mesh(8))
+    with pytest.warns(MeshDeclinedWarning, match="fault runs"):
+        y1, r1 = t.run(A, x, backend="jax", faults=real, mesh=tile_mesh(8))
     assert "+mesh" not in r1.backend
     np.testing.assert_array_equal(y0, y1)
     # and a sampled FaultModel stream: same seed, same draws, mesh or not
     fm = FaultModel(p_sa0=0.002)
     yf0, _ = t.run(A, x, backend="numpy", faults=fm, rng=7)
-    yf1, _ = t.run(A, x, backend="numpy", faults=fm, rng=7,
-                   mesh=tile_mesh(8))
+    with pytest.warns(MeshDeclinedWarning, match="fault runs"):
+        yf1, _ = t.run(A, x, backend="numpy", faults=fm, rng=7,
+                       mesh=tile_mesh(8))
     np.testing.assert_array_equal(yf0, yf1)
 
 

@@ -47,8 +47,7 @@ def _build_plan(kind):
 
 
 def _store(tmp_path):
-    # never repoint the process-wide jax compilation cache from tests
-    return PlanStore(tmp_path / "store", configure_jax_cache=False)
+    return PlanStore(tmp_path / "store")
 
 
 # ---------------------------------------------------------------------------
@@ -99,7 +98,7 @@ def test_schema_bump_loads_as_empty(tmp_path, monkeypatch):
     key = ("k",)
     store.put(key, _build_plan("binary_matvec").compile())
     monkeypatch.setattr(plan_store, "SCHEMA", plan_store.SCHEMA + 1)
-    fresh = PlanStore(store.path, configure_jax_cache=False)
+    fresh = PlanStore(store.path)
     assert fresh.load(key) is None
     assert fresh.corrupt == 1 and fresh.misses == 1 and fresh.hits == 0
     # the stale entry was dropped so the next writer replaces it
@@ -115,7 +114,7 @@ def test_corrupt_entry_loads_as_miss(tmp_path, mangle):
     data = p.read_bytes()
     p.write_bytes(data[: len(data) // 3] if mangle == "truncate"
                   else b"this is not a zipfile")
-    fresh = PlanStore(store.path, configure_jax_cache=False)
+    fresh = PlanStore(store.path)
     assert fresh.load(key) is None and fresh.corrupt == 1
     # a clean re-put recovers the slot
     assert fresh.put(key, _build_plan("matvec").compile())
@@ -127,7 +126,7 @@ def test_renamed_entry_fails_plan_key_check(tmp_path):
     store.put(("a",), _build_plan("binary_matvec").compile())
     # impersonate another key by renaming the file to its digest
     os.rename(store.entry_path(("a",)), store.entry_path(("b",)))
-    fresh = PlanStore(store.path, configure_jax_cache=False)
+    fresh = PlanStore(store.path)
     assert fresh.load(("b",)) is None and fresh.corrupt == 1
 
 
@@ -154,7 +153,7 @@ _WRITER = textwrap.dedent("""
     sys.path.insert(0, sys.argv[2])
     from repro.core import BinaryMatvecPlan
     from repro.serve.plan_store import PlanStore
-    store = PlanStore(sys.argv[1], configure_jax_cache=False)
+    store = PlanStore(sys.argv[1])
     cp = BinaryMatvecPlan(8, 32, rows=64, cols=256, parts=8).compile()
     for _ in range(int(sys.argv[3])):
         assert store.put(("shared",), cp)
@@ -166,7 +165,7 @@ def test_two_process_concurrent_writers_atomic(tmp_path):
     procs = [subprocess.Popen(
         [sys.executable, "-c", _WRITER, str(store.path), SRC, "10"])
         for _ in range(2)]
-    reader = PlanStore(store.path, configure_jax_cache=False)
+    reader = PlanStore(store.path)
     loads = 0
     while any(p.poll() is None for p in procs):
         if reader.load(("shared",)) is not None:
@@ -241,11 +240,6 @@ def test_restart_round_trip_async_admit_path(tmp_path):
 
 def test_env_default_store(tmp_path, monkeypatch):
     """$MATPIM_PLAN_STORE names the default path for every new service."""
-    try:
-        import jax
-        saved = jax.config.jax_compilation_cache_dir
-    except Exception:
-        jax = saved = None
     monkeypatch.setenv(plan_store.STORE_ENV, str(tmp_path / "envstore"))
     plan_store.reset_default_store()
     try:
@@ -260,8 +254,31 @@ def test_env_default_store(tmp_path, monkeypatch):
         assert PlanService(**GEOM, store=False).store is None
     finally:
         plan_store.reset_default_store()
-        if jax is not None:      # undo the env store's jax-cache repoint
-            jax.config.update("jax_compilation_cache_dir", saved)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placed_from_outside(tmp_path, monkeypatch, from_env):
+    """$JAX_COMPILATION_CACHE_DIR, when set, is the only compile cache;
+    otherwise it is the checkout's fixed .jax_cache. Building a store (or
+    a service on one) never moves it."""
+    import jax
+
+    from repro import compile_cache
+
+    want = str(tmp_path / "xla-cache") if from_env \
+        else str(compile_cache.DEFAULT_CACHE_DIR)
+    if from_env:
+        monkeypatch.setenv(compile_cache.CACHE_ENV, want)
+    else:
+        monkeypatch.delenv(compile_cache.CACHE_ENV, raising=False)
+    try:
+        assert compile_cache.configure_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        PlanService(**GEOM, store=PlanStore(tmp_path / "store"))
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        monkeypatch.undo()                  # the suite's own placement
+        compile_cache.configure_compile_cache()
 
 
 # ---------------------------------------------------------------------------
@@ -303,3 +320,26 @@ def test_prewarm_opt_out_restores_inline_warmup(tmp_path):
     s = warm.stats
     assert s.store_hits == s.misses > 0 and s.prewarms == 0
     assert s.warmup_s > 0                    # first batch pays it inline
+
+
+def test_failed_prewarm_is_recorded(tmp_path, monkeypatch):
+    """A warm-up that raises is counted and its traceback kept; the first
+    batch then warms inline and still serves correct results."""
+    store = _store(tmp_path)
+    cold = PlanService(**GEOM, store=store)
+    first = _traffic(cold, np.random.default_rng(17))
+
+    def broken_warm(self, cp):
+        raise RuntimeError("warm-up exploded")
+
+    monkeypatch.setattr(PlanService, "_warm_executors", broken_warm)
+    warm = PlanService(**GEOM, store=store)
+    second = _traffic(warm, np.random.default_rng(17))
+    warm.close()
+    s = warm.stats
+    assert s.store_hits == s.misses > 0
+    assert s.prewarm_errors == s.store_hits and s.prewarms == 0
+    assert "RuntimeError: warm-up exploded" in warm.last_prewarm_error
+    for a, b in zip(first, second):
+        np.testing.assert_array_equal(np.asarray(a.result),
+                                      np.asarray(b.result))

@@ -488,7 +488,7 @@ def test_cache_stats_field_whitelist():
 
     expected = {"hits", "misses", "evictions", "requests", "batches",
                 "units", "compile_s", "warmup_s", "async_compiles",
-                "store_hits", "prewarms"}
+                "store_hits", "prewarms", "prewarm_errors"}
     fields = {f.name for f in dc.fields(CacheStats)}
     assert fields == expected, (
         f"CacheStats schema drifted: added={sorted(fields - expected)} "
