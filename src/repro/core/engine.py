@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib.util
+import itertools
 import time
 from typing import Dict, List, Optional
 
@@ -512,17 +513,67 @@ def jax_unfused_body(cp: CompiledProgram):
     return body
 
 
-def _build_jax_runner(cp: CompiledProgram):
+def _pack_spanned(mem: np.ndarray, call: Optional[int]) -> np.ndarray:
+    """:func:`_pack` under the ``engine.pack`` span of engine call ``call``."""
+    B = mem.shape[0]
+    with _span("engine.pack", call=call, words=word_count(B), crossbars=B):
+        return _pack(mem)
+
+
+def _unpack_spanned(words: List[np.ndarray], B: int, R: int, C: int,
+                    call: Optional[int]) -> np.ndarray:
+    """Stack the replayed words and :func:`_unpack` them, under the
+    ``engine.unpack`` span of engine call ``call``; empties ``words``."""
+    with _span("engine.unpack", call=call, words=len(words)):
+        stacked = np.stack(words)
+        # free the per-word host copies first: held through the unpack,
+        # they made it ~1.3 s slower per 1024-crossbar call on a TPU v5e host
+        words.clear()
+        return _unpack(stacked, B, R, C)
+
+
+def replay_words(cp: CompiledProgram, mem: np.ndarray, run,
+                 call: Optional[int] = None, word_args=None) -> np.ndarray:
+    """Pack ``mem``, replay its packed words through the jitted ``run`` one
+    word at a time, and unpack: the host path every multi-word jax runner
+    shares.
+
+    Each word is copied to the device, replayed and copied back before the
+    next (``np.asarray`` blocks on the result). ``word_args(w, buf)`` turns
+    word ``w``'s host buffer into the host arguments of ``run`` (default:
+    the buffer alone). Spans, all tagged with the engine ``call`` id:
+    ``engine.pack``; per word ``engine.word`` with children ``engine.h2d``
+    (host arguments to the device), ``engine.replay`` (dispatch of ``run``)
+    and ``engine.d2h`` (wait for the result and copy it back);
+    ``engine.unpack``.
+    """
     import jax
     import jax.numpy as jnp
 
+    B = mem.shape[0]
+    bufs = _pack_spanned(mem, call)                # (W, C1, R1)
+    outs = []
+    for w, buf in enumerate(bufs):
+        with _span("engine.word", call=call, word=w, bytes=buf.nbytes):
+            args = (buf,) if word_args is None else word_args(w, buf)
+            with _span("engine.h2d", call=call, word=w):
+                args = jax.tree_util.tree_map(jnp.asarray, args)
+            with _span("engine.replay", call=call, word=w):
+                res = run(*args)
+            del args       # no device buffer outlives its word
+            with _span("engine.d2h", call=call, word=w):
+                outs.append(np.asarray(res))
+            del res
+    return _unpack_spanned(outs, B, cp.rows, cp.cols, call)
+
+
+def _build_jax_runner(cp: CompiledProgram):
+    import jax
+
     run = jax.jit(jax_unfused_body(cp))
 
-    def runner(mem_np: np.ndarray) -> np.ndarray:
-        B = mem_np.shape[0]
-        bufs = _pack(mem_np)                       # (W, C1, R1)
-        out = np.stack([np.asarray(run(jnp.asarray(b))) for b in bufs])
-        return _unpack(out, B, cp.rows, cp.cols)
+    def runner(mem_np: np.ndarray, call: Optional[int] = None) -> np.ndarray:
+        return replay_words(cp, mem_np, run, call)
 
     return runner
 
@@ -618,41 +669,47 @@ def _build_jax_runner_faulty(cp: CompiledProgram):
         return buf
 
     def runner(mem_np: np.ndarray, faults: FaultModel,
-               rng: np.random.Generator) -> np.ndarray:
+               rng: np.random.Generator,
+               call: Optional[int] = None) -> np.ndarray:
         # _execute_impl chunks FaultModel batches at WORD_BITS, so the
         # canonical pack is always a single word here
         B = mem_np.shape[0]
         sa0, sa1 = sample_stuck_words(faults, B, cp.rows, cp.cols, rng)
         sa0, sa1 = sa0[0], sa1[0]
-        buf = _pack(mem_np)[0]
+        buf = _pack_spanned(mem_np, call)[0]
         buf = (buf | sa1) & ~sa0                 # cells are stuck from t=0
         key = jax.random.PRNGKey(int(rng.integers(0, 2**31 - 1)))
         out = np.asarray(run(jnp.asarray(buf), key, jnp.asarray(sa0),
                              jnp.asarray(sa1), jnp.float32(faults.p_switch),
                              jnp.float32(faults.p_init)))
-        return _unpack(out[None], B, cp.rows, cp.cols)
+        return _unpack_spanned([out], B, cp.rows, cp.cols, call)
 
     return runner
 
 
 def _run_jax(cp: CompiledProgram, mem: np.ndarray,
              faults: Optional[FaultModel] = None,
-             rng: Optional[np.random.Generator] = None) -> np.ndarray:
+             rng: Optional[np.random.Generator] = None,
+             call: Optional[int] = None) -> np.ndarray:
     if faults is not None:
         runner = cp._caches.get("jax_runner_faulty")
         if runner is None:
             runner = cp._caches["jax_runner_faulty"] = \
                 _build_jax_runner_faulty(cp)
-        return runner(mem, faults, as_rng(rng))
+        return runner(mem, faults, as_rng(rng), call)
     runner = cp._caches.get("jax_runner")
     if runner is None:
         runner = cp._caches["jax_runner"] = _build_jax_runner(cp)
-    return runner(mem)
+    return runner(mem, call)
 
 
 # ---------------------------------------------------------------------------
 # Public entry point
 # ---------------------------------------------------------------------------
+
+
+# engine call ids: every span of one execute() call carries the same one
+_CALLS = itertools.count(1)
 
 
 def _ambient_mesh():
@@ -741,9 +798,10 @@ def execute(
     t0 = time.perf_counter()
     if mesh is None:
         mesh = _ambient_mesh()
-    with _span("engine.execute", backend=backend) as sp:
+    call = next(_CALLS)
+    with _span("engine.execute", backend=backend, call=call) as sp:
         res = _execute_impl(cp, mem, backend, max_batch, faults, rng, tunings,
-                            mesh)
+                            mesh, call)
         sp.set(resolved=res.backend, cycles=res.cycles)
     wall_us = (time.perf_counter() - t0) * 1e6
     label = res.backend.split("@", 1)[0]
@@ -780,6 +838,7 @@ def _execute_impl(
     rng,
     tunings,
     mesh=None,
+    call: Optional[int] = None,
 ) -> EngineResult:
     from .fused import (build_jax_fused, build_jax_fused_real,
                         jax_fuse_eligible, run_numpy_fused, schedule_for)
@@ -895,12 +954,12 @@ def _execute_impl(
             chunks.append(run(cp, sub, f, rng) if f is not None
                           else run(cp, sub))
         elif variant == "fused":
-            chunks.append(build_jax_fused_real(cp)(sub, f)
+            chunks.append(build_jax_fused_real(cp)(sub, f, call)
                           if f is not None
-                          else build_jax_fused(cp)(sub))
+                          else build_jax_fused(cp)(sub, call))
         else:
-            chunks.append(_run_jax(cp, sub, f, rng) if f is not None
-                          else _run_jax(cp, sub))
+            chunks.append(_run_jax(cp, sub, f, rng, call) if f is not None
+                          else _run_jax(cp, sub, call=call))
     out = chunks[0] if len(chunks) == 1 else np.concatenate(chunks, axis=0)
     if squeeze:
         out = out[0]

@@ -295,7 +295,7 @@ def _build_jax_fused(cp: CompiledProgram,
     import jax.numpy as jnp
     from jax import lax
 
-    from .engine import BIT_GATES, WORD_BITS, _pack, _unpack
+    from .engine import BIT_GATES, WORD_BITS, replay_words
 
     sched = schedule_for(cp)
     dt = jnp.dtype(np.uint32)
@@ -489,12 +489,9 @@ def _build_jax_fused(cp: CompiledProgram,
     if not realization:
         run_ideal = jax.jit(ideal_body)
 
-        def runner(mem_np: np.ndarray) -> np.ndarray:
-            B = mem_np.shape[0]
-            bufs = _pack(mem_np)                   # (W, C1, R1)
-            out = np.stack([np.asarray(run_ideal(jnp.asarray(b)))
-                            for b in bufs])
-            return _unpack(out, B, cp.rows, cp.cols)
+        def runner(mem_np: np.ndarray,
+                   call: Optional[int] = None) -> np.ndarray:
+            return replay_words(cp, mem_np, run_ideal, call)
         return runner
 
     @jax.jit
@@ -505,7 +502,7 @@ def _build_jax_fused(cp: CompiledProgram,
         return buf
 
     def pack_realization(real: FaultRealization) -> tuple:
-        """Segment-indexed runtime arrays for ONE canonical word of ``real``
+        """Segment-indexed host arrays for ONE canonical word of ``real``
         (batch <= 32; masks sampled per original cycle; sorted-slot
         permutation applied here, host-side)."""
         sa = tuple(a[0] for a in real.stuck_words())
@@ -516,7 +513,7 @@ def _build_jax_fused(cp: CompiledProgram,
                 for j, t in enumerate(range(seg.t0, seg.t1)):
                     for i in range(cp.I):
                         init[j, i] = real.init_words(t, i)[0]
-                rxs.append({"init": jnp.asarray(init)})
+                rxs.append({"init": init})
                 continue
             line = R1 if seg.mode == MODE_COL else C1
             sw = np.zeros((seg.length, seg.W, line), np.uint32)
@@ -531,20 +528,19 @@ def _build_jax_fused(cp: CompiledProgram,
                     sw = np.concatenate(
                         [sw, np.zeros((pad, seg.W, line), np.uint32)])
                 sw = sw.reshape(-1, CHUNK, seg.W, line)
-            rxs.append({"switch": jnp.asarray(sw)})
+            rxs.append({"switch": sw})
         return sa, tuple(rxs)
 
-    def runner(mem_np: np.ndarray, real: FaultRealization) -> np.ndarray:
+    def runner(mem_np: np.ndarray, real: FaultRealization,
+               call: Optional[int] = None) -> np.ndarray:
         B = mem_np.shape[0]
-        bufs = _pack(mem_np)                       # (W, C1, R1)
-        out = np.empty_like(bufs)
-        for w in range(bufs.shape[0]):
+
+        def word_args(w, buf):
             rw = real.narrow(WORD_BITS * w, min(WORD_BITS * (w + 1), B))
             sa, rxs = pack_realization(rw)
-            buf = (bufs[w] | sa[1]) & ~sa[0]
-            out[w] = np.asarray(run_real(
-                jnp.asarray(buf), tuple(jnp.asarray(a) for a in sa), rxs))
-        return _unpack(out, B, cp.rows, cp.cols)
+            return (buf | sa[1]) & ~sa[0], sa, rxs
+
+        return replay_words(cp, mem_np, run_real, call, word_args)
     return runner
 
 

@@ -12,9 +12,17 @@ track). The recorded events are Chrome-trace *complete* events (``"ph":
 "X"`` with microsecond ``ts``/``dur``), the format both ``chrome://tracing``
 and https://ui.perfetto.dev load directly.
 
+Profiler clock — whenever a ``jax.profiler`` session is collecting, every
+span also opens a ``jax.profiler.TraceAnnotation`` of the same name whose
+args (and later :meth:`Span.set` attributes) become xplane event stats, so
+the spans line up with device activity in the profiler's trace. This holds
+whether or not tracing is enabled here; jax is looked up in
+``sys.modules``, never imported, so this module stays stdlib-only.
+
 Cost model — this module is imported by the engine hot path, so the
-**disabled** path is a module-global boolean check plus returning a no-op
-singleton context manager (no allocation, no clock read; asserted <2% of
+**disabled** path is a module-global boolean check, the profiler's own
+"collecting?" check once jax is loaded, and returning a no-op singleton
+context manager (no allocation, no clock read; asserted <2% of
 ``engine.execute`` wall in ``tests/test_obs.py``). Tracing only pays for
 clock reads and one dict append per span when enabled.
 
@@ -38,6 +46,7 @@ from __future__ import annotations
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from typing import List, Optional
@@ -54,6 +63,21 @@ _TRACER: Optional["Tracer"] = None
 # nests by time containment, the depth makes flat consumers' lives easier)
 _DEPTH: contextvars.ContextVar = contextvars.ContextVar(
     "matpim_span_depth", default=0)
+
+
+# jax.profiler.TraceAnnotation once jax has been imported by someone else
+_ANNOTATION = None
+
+
+def _profiling() -> bool:
+    """Whether a jax profiler session is collecting host events."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is None:
+            return False
+        _ANNOTATION = prof.TraceAnnotation
+    return _ANNOTATION.is_enabled()
 
 
 class _NullSpan:
@@ -74,17 +98,42 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class Span:
-    """One live span; records a complete event into its tracer on exit."""
+class _ProfilerSpan:
+    """A span seen only by the jax profiler (tracing here is disabled)."""
 
-    __slots__ = ("name", "args", "_t0", "_tok", "_tracer")
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, args: dict):
+        self._ann = _ANNOTATION(name, **args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def set(self, **attrs) -> "_ProfilerSpan":
+        self._ann.set_metadata(**attrs)
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+
+class Span:
+    """One live span; records a complete event into its tracer on exit,
+    mirrored onto the profiler's clock while a profiler collects."""
+
+    __slots__ = ("name", "args", "_t0", "_tok", "_tracer", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._ann = _ANNOTATION(name, **args) if _profiling() else None
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._tok = _DEPTH.set(_DEPTH.get() + 1)
         self._t0 = time.perf_counter_ns()
         return self
@@ -92,12 +141,16 @@ class Span:
     def set(self, **attrs) -> "Span":
         """Attach attributes discovered mid-span (e.g. a resolved backend)."""
         self.args.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
         _DEPTH.reset(self._tok)
         self._tracer._emit(self.name, self._t0, t1, _DEPTH.get(), self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -187,11 +240,14 @@ def span(name: str, **args):
     """Open a traced span (context manager).
 
     The disabled path returns a shared no-op object — callers never need to
-    guard instrumentation sites themselves.
+    guard instrumentation sites themselves — unless a jax profiler is
+    collecting, which then sees the span as a ``TraceAnnotation``.
     """
-    if not _ENABLED:
-        return _NULL_SPAN
-    return Span(_TRACER, name, args)
+    if _ENABLED:
+        return Span(_TRACER, name, args)
+    if _profiling():
+        return _ProfilerSpan(name, args)
+    return _NULL_SPAN
 
 
 # $MATPIM_TRACE: enable at import; any value other than "1" is the output

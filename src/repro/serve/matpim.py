@@ -504,7 +504,6 @@ class PlanService:
                 if info.get("warm_error"):
                     self.stats.prewarm_errors += 1
                     self.last_prewarm_error = info["warm_error"]
-                    _metrics.counter("serve.prewarm_errors").inc()
                 dt = job.wall_s - info.get("warm_s", 0.0)
                 self.stats.compile_s += dt
                 _metrics.counter("serve.compile_s").inc(dt)
@@ -517,7 +516,6 @@ class PlanService:
                     self.stats.warmup_s += info["warm_s"]
                     self.stats.prewarms += 1
                     _metrics.counter("serve.warmup_s").inc(info["warm_s"])
-                    _metrics.counter("serve.prewarms").inc()
             _metrics.histogram("serve.compile_wait_us").observe(
                 (job.finished_s - job.submitted_s) * 1e6)
             landed += 1
@@ -1033,7 +1031,6 @@ class PlanService:
                    pending_units=self.pending_units,
                    starved=bool(starved), buckets=len(ready)):
             done = self._run_buckets(ready)
-        _metrics.counter("serve.steps").inc()
         _metrics.gauge("serve.queue_depth_units").set(self.pending_units)
         return done
 

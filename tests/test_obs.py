@@ -95,6 +95,53 @@ def test_chrome_trace_save_roundtrip(tracer, tmp_path):
     assert [e["name"] for e in d["traceEvents"]] == ["a"]
 
 
+def _profiled(tmp_path, fn, prefix):
+    """Run ``fn`` under ``jax.profiler.trace``; its result and the host
+    events whose names start with ``prefix``, as ``(name, start_ns, end_ns,
+    stats)`` in start order."""
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        out = fn()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+            {k: v for k, v in e.stats})
+           for plane in pd.planes if plane.name.startswith("/host:")
+           for line in plane.lines for e in line.events
+           if e.name.startswith(prefix)]
+    return out, sorted(evs, key=lambda ev: ev[1])
+
+
+@pytest.mark.parametrize("obs_on", [True, False], ids=["enabled", "disabled"])
+def test_span_reaches_the_profiler_with_its_args(tmp_path, obs_on):
+    tr = trace.enable() if obs_on else None
+    try:
+        def traced():
+            with trace.span("obs.outer", call=7):
+                with trace.span("obs.inner", word=2, bytes=4096) as sp:
+                    assert sp is not trace._NULL_SPAN
+                    sp.set(resolved="jax-fused")
+
+        _, evs = _profiled(tmp_path, traced, "obs.")
+    finally:
+        if obs_on:
+            trace.disable()
+    assert [name for name, *_ in evs] == ["obs.outer", "obs.inner"]
+    (_, o0, o1, outer), (_, i0, i1, inner) = evs
+    assert o0 <= i0 and i1 <= o1
+    assert outer == {"call": 7}
+    assert inner == {"word": 2, "bytes": 4096, "resolved": "jax-fused"}
+    if obs_on:     # the perf_counter events are recorded as before
+        got = [(e["name"], e["args"]) for e in tr.events()]
+        assert got == [("obs.inner", {"depth": 1, "word": 2, "bytes": 4096,
+                                      "resolved": "jax-fused"}),
+                       ("obs.outer", {"depth": 0, "call": 7})]
+    # no profiler collecting and tracing disabled: the shared no-op again
+    assert trace.span("obs.after", x=1) is trace._NULL_SPAN
+
+
 # ---------------------------------------------------------------------------
 # metrics.py
 # ---------------------------------------------------------------------------
@@ -176,6 +223,57 @@ def test_engine_execute_publishes_metrics_and_span(tracer):
     assert ev["args"]["backend"] == "numpy"
     assert ev["args"]["resolved"] == res.backend
     assert ev["args"]["cycles"] == res.cycles
+
+
+def _batch_of_64():
+    plan = BinaryMatvecPlan(8, 16, rows=64, cols=256, parts=8)
+    rng = np.random.default_rng(5)
+    mems = np.zeros((64, plan.rows, plan.cols), dtype=np.uint8)
+    for b in range(64):
+        plan.load_into(mems[b], rng.choice([-1, 1], size=(8, 16)),
+                       rng.choice([-1, 1], size=16))
+    return plan, mems
+
+
+@pytest.mark.parametrize("runner", ["fused", "realization", "unfused"])
+def test_engine_host_path_spans_under_the_profiler(tmp_path, runner):
+    """Two packed words through each jax word loop: one engine.pack, one
+    engine.word per word with its h2d/replay/d2h children, one
+    engine.unpack, all inside engine.execute under one call id, and the
+    same bits as without the profiler."""
+    from repro.device.faults import FaultModel, FaultRealization
+    plan, mems = _batch_of_64()
+    cp = plan.compile()
+    backend = "jax-unfused" if runner == "unfused" else "jax-fused"
+    faults = None
+    if runner == "realization":
+        faults = FaultRealization.sample(
+            FaultModel(p_sa0=0.01, p_switch=0.01), 64, plan.rows,
+            plan.cols, cp.n_cycles, cp.W, cp.I, rng=3)
+    want = plan.execute_batch(mems, backend=backend, faults=faults)  # warm
+    got, evs = _profiled(
+        tmp_path,
+        lambda: plan.execute_batch(mems, backend=backend, faults=faults),
+        "engine.")
+    np.testing.assert_array_equal(got.mem, want.mem)
+    assert got.backend == backend
+
+    names = [name for name, *_ in evs]
+    assert names == (["engine.execute", "engine.pack"]
+                     + ["engine.word", "engine.h2d", "engine.replay",
+                        "engine.d2h"] * 2 + ["engine.unpack"])
+    (_, x0, x1, ex), *inner = evs
+    assert len({st["call"] for *_, st in evs}) == 1
+    assert all(x0 <= s and e <= x1 for _, s, e, _ in inner)
+    assert inner[0][3] == {"call": ex["call"], "words": 2, "crossbars": 64}
+    assert inner[-1][3] == {"call": ex["call"], "words": 2}
+    words = [i for i, ev in enumerate(inner) if ev[0] == "engine.word"]
+    for w, i in enumerate(words):
+        _, w0, w1, st = inner[i]
+        assert st == {"call": ex["call"], "word": w,
+                      "bytes": 4 * (plan.cols + 1) * (plan.rows + 1)}
+        for _, s, e, child in inner[i + 1:i + 4]:
+            assert w0 <= s and e <= w1 and child["word"] == w
 
 
 def test_engine_fault_run_sets_fault_gauges():
