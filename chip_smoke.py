@@ -18,13 +18,14 @@ not go through the simulator:
 * ``batch``    1024 crossbars of ``BinaryMatvecPlan(1024, 384)`` (32 packed
                words, the Monte-Carlo sample count of the device benchmark)
                through ``execute_batch(backend="jax")``: bit for bit the
-               numpy-fused backend's memory, cycles and stats.
+               numpy-fused backend's memory, cycles and stats; so are the
+               first 37 (a full word and one of 5, shipped padded to 8).
 * ``faults``   32 crossbars under ``FaultModel(p_switch=1e-3)`` on the jax
                faulty scan; the ideal ``FaultModel()`` equals the fault-free
                run bit for bit, and a repeated seed repeats the draws. A
-               sampled ``FaultRealization`` (on a 64x256 array: its masks
-               are per cell and cycle) replays on jax-fused bit for bit as
-               on numpy-fused.
+               sampled ``FaultRealization`` over 37 crossbars (on a 64x256
+               array: its masks are per cell and cycle) replays on
+               jax-fused bit for bit as on numpy-fused.
 * ``pallas``   ``backend="pallas"`` on the paper's binary matvec and on the
                paper's matvec and conv shapes at N=8, the widest N whose f32
                accumulation is exact (at N=32 ``pallas_eligible`` refuses);
@@ -160,6 +161,9 @@ def phase_batch(rng, geom=GEOM, shape=(1024, 384), B=1024) -> dict:
           "batch: cycles or stats differ from numpy-fused")
     check(np.array_equal(res.mem, ref.mem),
           "batch: memory differs from numpy-fused")
+    part = plan.execute_batch(mems[:37], backend="jax")
+    check(np.array_equal(part.mem, ref.mem[:37]),
+          "batch: a partial word differs from numpy-fused")
     return {"backend": res.backend, "crossbars": B,
             "packed_words": -(-B // 32), "cycles": res.cycles}
 
@@ -191,10 +195,10 @@ def phase_faults(rng, geom=GEOM, shape=(1024, 384), B=32,
     # host arrays are per cell and cycle, so this runs on a 64x256 array
     small = BinaryMatvecPlan(16, 24, rows=64, cols=256, parts=8)
     scp = small.compile()
-    smems, _ = _bmv_batch(rng, small, B)
+    smems, _ = _bmv_batch(rng, small, B + 5)     # one partial word too
     real = FaultRealization.sample(
         FaultModel(p_sa0=2e-3, p_sa1=1e-3, p_switch=1e-3, p_init=1e-3),
-        B, small.rows, small.cols, scp.n_cycles, scp.W, scp.I, rng=3)
+        B + 5, small.rows, small.cols, scp.n_cycles, scp.W, scp.I, rng=3)
     rj = small.execute_batch(smems, backend="jax-fused", faults=real)
     rn = small.execute_batch(smems, backend="numpy-fused", faults=real)
     check(np.array_equal(rj.mem, rn.mem),

@@ -32,10 +32,13 @@ comes from, and what makes the tiled multi-crossbar scale-out
 (``tiling.py``) cheap.
 
 The word width never tracks the batch: the numpy executors broadcast over
-the leading W axis, and the jitted jax bodies stay per-word ``(C+1, R+1)``
-with a host-side loop over words — so every batch size shares the SAME
-jitted runner (one XLA compile per program, keyed dtype-free on
-``cp._caches``), instead of one runner per batch-derived word dtype.
+the leading W axis, and the jax bodies stay per-word ``(C+1, R+1)``, each
+jitted inside a word program that takes the word's uint8 crossbars and
+packs and unpacks on the device (:func:`device_word_program`), with a
+host-side loop over words (:func:`replay_words`) — so every batch size
+shares the SAME runner (keyed dtype-free on ``cp._caches``; at most six
+XLA compiles per program, one per shipped word width), instead of one
+runner per batch-derived word dtype.
 The only transparent chunking left is ``FaultModel`` sampling, which keeps
 the historic chunk sizes (64 on numpy, 32 on jax) so same-seed Monte-Carlo
 draws stay bit-identical across releases.
@@ -49,8 +52,10 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import itertools
+import sys
+import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -513,67 +518,173 @@ def jax_unfused_body(cp: CompiledProgram):
     return body
 
 
-def _pack_spanned(mem: np.ndarray, call: Optional[int]) -> np.ndarray:
-    """:func:`_pack` under the ``engine.pack`` span of engine call ``call``."""
-    B = mem.shape[0]
-    with _span("engine.pack", call=call, words=word_count(B), crossbars=B):
-        return _pack(mem)
+def padded_width(n: int) -> int:
+    """Crossbars shipped for a word of ``n <= 32``: the next power of two,
+    so a word program compiles at most six widths (1, 2, 4, ..., 32).
+
+    >>> [padded_width(n) for n in (1, 2, 3, 5, 17, 32)]
+    [1, 2, 4, 8, 32, 32]
+    """
+    return 1 << (int(n) - 1).bit_length()
 
 
-def _unpack_spanned(words: List[np.ndarray], B: int, R: int, C: int,
-                    call: Optional[int]) -> np.ndarray:
-    """Stack the replayed words and :func:`_unpack` them, under the
-    ``engine.unpack`` span of engine call ``call``; empties ``words``."""
-    with _span("engine.unpack", call=call, words=len(words)):
-        stacked = np.stack(words)
-        # free the per-word host copies first: held through the unpack,
-        # they made it ~1.3 s slower per 1024-crossbar call on a TPU v5e host
-        words.clear()
-        return _unpack(stacked, B, R, C)
+def word_widths(max_batch: Optional[int] = None) -> Tuple[int, ...]:
+    """Every width :func:`replay_words` can ship for batches (or
+    ``max_batch`` chunks) of any size: what a warm-up must run once per
+    word program so that no served batch compiles inline.
+
+    >>> word_widths(), word_widths(5), word_widths(1)
+    ((1, 2, 4, 8, 16, 32), (1, 2, 4, 8), (1,))
+    """
+    top = padded_width(min(int(max_batch or WORD_BITS), WORD_BITS))
+    return tuple(1 << k for k in range(top.bit_length()))
 
 
-def replay_words(cp: CompiledProgram, mem: np.ndarray, run,
-                 call: Optional[int] = None, word_args=None) -> np.ndarray:
-    """Pack ``mem``, replay its packed words through the jitted ``run`` one
-    word at a time, and unpack: the host path every multi-word jax runner
-    shares.
+def device_word_program(body, rows: int, cols: int):
+    """Jit ``body`` (one canonical ``(C+1, R+1)`` uint32 word -> word, plus
+    any further arguments) into the program one word of crossbars runs.
 
-    Each word is copied to the device, replayed and copied back before the
-    next (``np.asarray`` blocks on the result). ``word_args(w, buf)`` turns
-    word ``w``'s host buffer into the host arguments of ``run`` (default:
-    the buffer alone). Spans, all tagged with the engine ``call`` id:
-    ``engine.pack``; per word ``engine.word`` with children ``engine.h2d``
-    (host arguments to the device), ``engine.replay`` (dispatch of ``run``)
-    and ``engine.d2h`` (wait for the result and copy it back);
-    ``engine.unpack``.
+    It takes ``P <= 32`` uint8 crossbars and returns them, both ways as the
+    same bytes viewed as uint32 ``(P, R, ceil(C/4))`` (each row padded with
+    zero bytes to a multiple of 4): a TPU v5e copies a uint8 block to the
+    host ~3.5x slower than the same bytes as uint32 (PERF.md). Inside the
+    one program the crossbars are packed into the canonical word (bit b =
+    crossbar b; the pad row, the pad column and bits ``>= P`` are zero),
+    replayed by ``body`` and unpacked again, so the host neither packs nor
+    unpacks.
     """
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
-    B = mem.shape[0]
-    bufs = _pack_spanned(mem, call)                # (W, C1, R1)
-    outs = []
-    for w, buf in enumerate(bufs):
-        with _span("engine.word", call=call, word=w, bytes=buf.nbytes):
-            args = (buf,) if word_args is None else word_args(w, buf)
-            with _span("engine.h2d", call=call, word=w):
-                args = jax.tree_util.tree_map(jnp.asarray, args)
-            with _span("engine.replay", call=call, word=w):
-                res = run(*args)
-            del args       # no device buffer outlives its word
-            with _span("engine.d2h", call=call, word=w):
-                outs.append(np.asarray(res))
-            del res
-    return _unpack_spanned(outs, B, cp.rows, cp.cols, call)
+    R, C = rows, cols
+    C4 = -(-C // 4)
+
+    def replay_word(x, *args):
+        P = x.shape[0]
+        # runs once per trace, i.e. once per compiled width
+        _metrics.counter("engine.device_pack.traces").inc()
+        x = lax.bitcast_convert_type(x, jnp.uint8).reshape(P, R, 4 * C4)
+        bits = jnp.arange(P, dtype=jnp.uint32)[:, None, None]
+        word = lax.reduce(x[:, :, :C].astype(jnp.uint32) << bits,
+                          np.uint32(0), lax.bitwise_or, (0,))   # (R, C)
+        buf = jnp.pad(word.T, ((0, 1), (0, 1)))                 # (C+1, R+1)
+        buf = body(buf, *args)
+        y = ((buf[:C, :R].T[None] >> bits) & 1).astype(jnp.uint8)
+        y = jnp.pad(y, ((0, 0), (0, 0), (0, 4 * C4 - C)))
+        return lax.bitcast_convert_type(y.reshape(P, R, C4, 4), jnp.uint32)
+
+    return jax.jit(replay_word)
+
+
+# threads that copy each replayed word into the output: the copy overlaps
+# the next word's transfers, and its page faults on a fresh output (~1.15 s
+# a GB on one thread on a TPU v5e host) spread over cores
+COPY_THREADS = 4
+
+# the newest output of replay_words; see _output_like
+_spare_output: Optional[np.ndarray] = None
+_spare_lock = threading.Lock()
+
+
+def _output_like(mem: np.ndarray) -> np.ndarray:
+    """An uninitialised array like ``mem`` for :func:`replay_words` to fill.
+
+    It is the previous output again when that has the same shape and its
+    caller has dropped every array on it (no reference is left but this
+    module's), else a fresh array, which then becomes the one kept. So at
+    most one output's memory is held between calls, and a loop that drops
+    each result before the next call writes into memory it has touched
+    already: on a TPU v5e host, back-to-back 1024-crossbar calls took
+    ~0.52 s into fresh outputs (while the last output's memory was still
+    being released) against ~0.40 s into the reused one (PERF.md).
+    """
+    global _spare_output
+    with _spare_lock:
+        out = _spare_output
+        # references: the module's, ``out`` and getrefcount's argument
+        if (out is None or out.shape != mem.shape
+                or out.dtype != mem.dtype or sys.getrefcount(out) > 3):
+            out = _spare_output = np.empty_like(mem)
+        return out
+
+
+def replay_words(mem: np.ndarray, run, call: Optional[int] = None,
+                 word_args=None) -> np.ndarray:
+    """Replay ``mem`` through the word program ``run``
+    (:func:`device_word_program`) one packed word of 32 crossbars at a
+    time: the host path every multi-word jax runner shares.
+
+    The host ships each word's crossbars as they are, viewed as uint32 (a
+    partial word padded with zero crossbars to :func:`padded_width`, rows
+    with zero bytes where ``C`` is not a multiple of 4), dispatches ``run``
+    and fetches its result, which :data:`COPY_THREADS` threads copy into
+    one preallocated ``(B, R, C)`` output (:func:`_output_like`: the last
+    call's memory where its caller has let go of it) while the next word
+    runs; packing
+    and unpacking happen on the device. One word is on the device at a
+    time, and at most two fetched results are held on the host.
+    ``word_args(w)`` gives the further host arguments of ``run`` for word
+    ``w`` (default: none). Spans, all tagged with the engine ``call`` id:
+    per word ``engine.word`` (word, bytes shipped) with children
+    ``engine.h2d`` (arguments to the device), ``engine.replay`` (dispatch
+    of ``run``) and ``engine.d2h`` (wait for the result, fetch it, wait for
+    the previous word's copy and hand this one to the copy threads).
+    Counters: ``engine.device_pack.words`` and
+    ``engine.device_pack.padded_crossbars`` (``engine.device_pack.traces``
+    counts the word program's compiled widths).
+    """
+    from concurrent.futures import ThreadPoolExecutor
+
+    import jax
+    import jax.numpy as jnp
+
+    B, R, C = mem.shape
+    C4 = -(-C // 4)
+    out = _output_like(mem)
+    padded = 0
+    copies: list = []
+    with ThreadPoolExecutor(COPY_THREADS) as copier:
+        for w in range(word_count(B)):
+            lo = WORD_BITS * w
+            n = min(WORD_BITS, B - lo)
+            x = mem[lo:lo + n]
+            P = padded_width(n)
+            if P > n or 4 * C4 > C:
+                x = np.zeros((P, R, 4 * C4), np.uint8)
+                x[:n, :, :C] = mem[lo:lo + n]
+                padded += P - n
+            x = x.view(np.uint32)
+            with _span("engine.word", call=call, word=w, bytes=x.nbytes):
+                args = ((x,) if word_args is None
+                        else (x,) + tuple(word_args(w)))
+                with _span("engine.h2d", call=call, word=w):
+                    args = jax.tree_util.tree_map(jnp.asarray, args)
+                with _span("engine.replay", call=call, word=w):
+                    res = run(*args)
+                del args, x    # no device buffer outlives its word
+                with _span("engine.d2h", call=call, word=w):
+                    host = np.asarray(res).view(np.uint8)[:n, :, :C]
+                    for f in copies:
+                        f.result()
+                    cuts = np.linspace(0, n, min(COPY_THREADS, n) + 1,
+                                       dtype=int)
+                    copies = [copier.submit(np.copyto, out[lo + i:lo + j],
+                                            host[i:j])
+                              for i, j in zip(cuts[:-1], cuts[1:])]
+                del res, host
+        for f in copies:
+            f.result()
+    _metrics.counter("engine.device_pack.words").inc(word_count(B))
+    _metrics.counter("engine.device_pack.padded_crossbars").inc(padded)
+    return out
 
 
 def _build_jax_runner(cp: CompiledProgram):
-    import jax
-
-    run = jax.jit(jax_unfused_body(cp))
+    run = device_word_program(jax_unfused_body(cp), cp.rows, cp.cols)
 
     def runner(mem_np: np.ndarray, call: Optional[int] = None) -> np.ndarray:
-        return replay_words(cp, mem_np, run, call)
+        return replay_words(mem_np, run, call)
 
     return runner
 
@@ -676,13 +787,15 @@ def _build_jax_runner_faulty(cp: CompiledProgram):
         B = mem_np.shape[0]
         sa0, sa1 = sample_stuck_words(faults, B, cp.rows, cp.cols, rng)
         sa0, sa1 = sa0[0], sa1[0]
-        buf = _pack_spanned(mem_np, call)[0]
+        with _span("engine.pack", call=call, words=1, crossbars=B):
+            buf = _pack_word(mem_np)
         buf = (buf | sa1) & ~sa0                 # cells are stuck from t=0
         key = jax.random.PRNGKey(int(rng.integers(0, 2**31 - 1)))
         out = np.asarray(run(jnp.asarray(buf), key, jnp.asarray(sa0),
                              jnp.asarray(sa1), jnp.float32(faults.p_switch),
                              jnp.float32(faults.p_init)))
-        return _unpack_spanned([out], B, cp.rows, cp.cols, call)
+        with _span("engine.unpack", call=call, words=1):
+            return _unpack_word(out, B, cp.rows, cp.cols)
 
     return runner
 

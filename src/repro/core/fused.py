@@ -11,7 +11,8 @@ segment schedule attached at compile time — onto the two vectorized backends:
 * **jax-fused** (:func:`build_jax_fused`): ONE jitted function per program —
   batch-polymorphic over the canonical packed layout (the host loops the
   leading ``W = ceil(B/32)`` word axis around a per-word uint32 body, so
-  every batch size replays through the same XLA executable) — with **no
+  every batch size replays through one of at most six XLA executables,
+  one per word width shipped) — with **no
   per-cycle ``lax.switch`` and no cycle-granular scan carry**. Init segments
   lower to compile-time-constant
   ``jnp.where`` rectangles; short gate segments unroll to straight-line code
@@ -281,9 +282,11 @@ def _build_jax_fused(cp: CompiledProgram,
                      realization: bool = False, body_only: bool = False):
     """Build the canonical jitted fused runner for ``cp``.
 
-    The jitted body is a per-word uint32 transition on one ``(C+1, R+1)``
-    packed buffer; the returned runner loops the canonical ``W`` word axis
-    host-side, so ONE XLA executable serves every batch size. Returns
+    The body is a per-word uint32 transition on one ``(C+1, R+1)`` packed
+    buffer, jitted inside the word program that packs and unpacks on the
+    device (``engine.device_word_program``); the returned runner loops the
+    words host-side, so one executable per shipped width (at most six)
+    serves every batch size. Returns
     ``runner(mem)`` (ideal) or ``runner(mem, real)`` where ``real`` is a
     :class:`FaultRealization` packed to runtime arguments, so one jit serves
     every realization of the same shape. ``body_only=True`` instead returns
@@ -291,11 +294,11 @@ def _build_jax_fused(cp: CompiledProgram,
     seam the mesh executor vmaps and shard_maps
     (``repro.distributed.mesh_exec``).
     """
-    import jax
     import jax.numpy as jnp
     from jax import lax
 
-    from .engine import BIT_GATES, WORD_BITS, replay_words
+    from .engine import (BIT_GATES, WORD_BITS, device_word_program,
+                         replay_words)
 
     sched = schedule_for(cp)
     dt = jnp.dtype(np.uint32)
@@ -487,19 +490,20 @@ def _build_jax_fused(cp: CompiledProgram,
         return ideal_body
 
     if not realization:
-        run_ideal = jax.jit(ideal_body)
+        run_ideal = device_word_program(ideal_body, cp.rows, cp.cols)
 
         def runner(mem_np: np.ndarray,
                    call: Optional[int] = None) -> np.ndarray:
-            return replay_words(cp, mem_np, run_ideal, call)
+            return replay_words(mem_np, run_ideal, call)
         return runner
 
-    @jax.jit
-    def run_real(buf0, sa, rxs):
-        buf = buf0
+    def real_body(buf, sa, rxs):
+        buf = (buf | sa[1]) & ~sa[0]             # cells are stuck from t=0
         for fn, rx in zip(seg_fns, rxs):
             buf = fn(buf, sa, rx)
         return buf
+
+    run_real = device_word_program(real_body, cp.rows, cp.cols)
 
     def pack_realization(real: FaultRealization) -> tuple:
         """Segment-indexed host arrays for ONE canonical word of ``real``
@@ -535,12 +539,11 @@ def _build_jax_fused(cp: CompiledProgram,
                call: Optional[int] = None) -> np.ndarray:
         B = mem_np.shape[0]
 
-        def word_args(w, buf):
+        def word_args(w):
             rw = real.narrow(WORD_BITS * w, min(WORD_BITS * (w + 1), B))
-            sa, rxs = pack_realization(rw)
-            return (buf | sa[1]) & ~sa[0], sa, rxs
+            return pack_realization(rw)
 
-        return replay_words(cp, mem_np, run_real, call, word_args)
+        return replay_words(mem_np, run_real, call, word_args)
     return runner
 
 

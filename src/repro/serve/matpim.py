@@ -388,14 +388,16 @@ class PlanService:
         if backend in ("numpy", "auto", "numpy-fused", "numpy-unfused"):
             prewarm_replay(cp)
         if backend in ("jax", "jax-fused", "jax-unfused", "auto"):
-            from ..core.engine import execute, have_jax
+            from ..core.engine import execute, have_jax, word_widths
             if have_jax():
-                # a B=1 dummy jits THE canonical per-word runner — batch
-                # polymorphic, so this one warm serves every bucket; the run
-                # itself is a few ms on top
-                dummy = np.zeros((1, cp.rows, cp.cols), dtype=np.uint8)
-                execute(cp, dummy, backend="jax" if backend == "auto"
-                        else backend, max_batch=self.max_batch)
+                # one dummy per width a word can ship (a partial word pads
+                # to a power of two) jits THE canonical runner's every
+                # executable, so no bucket compiles inline; each run is a
+                # few ms on top
+                for width in word_widths(self.max_batch):
+                    dummy = np.zeros((width, cp.rows, cp.cols), np.uint8)
+                    execute(cp, dummy, backend="jax" if backend == "auto"
+                            else backend, max_batch=self.max_batch)
         return time.perf_counter() - t0
 
     def _prewarm_async(self, key: tuple, w) -> bool:

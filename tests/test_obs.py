@@ -237,10 +237,11 @@ def _batch_of_64():
 
 @pytest.mark.parametrize("runner", ["fused", "realization", "unfused"])
 def test_engine_host_path_spans_under_the_profiler(tmp_path, runner):
-    """Two packed words through each jax word loop: one engine.pack, one
-    engine.word per word with its h2d/replay/d2h children, one
-    engine.unpack, all inside engine.execute under one call id, and the
-    same bits as without the profiler."""
+    """Two packed words through each jax word loop: one engine.word per
+    word with its h2d/replay/d2h children, all inside engine.execute under
+    one call id, no host engine.pack or engine.unpack (the word program
+    packs and unpacks on the device), and the same bits as without the
+    profiler."""
     from repro.device.faults import FaultModel, FaultRealization
     plan, mems = _batch_of_64()
     cp = plan.compile()
@@ -259,21 +260,41 @@ def test_engine_host_path_spans_under_the_profiler(tmp_path, runner):
     assert got.backend == backend
 
     names = [name for name, *_ in evs]
-    assert names == (["engine.execute", "engine.pack"]
+    assert "engine.pack" not in names and "engine.unpack" not in names
+    assert names == (["engine.execute"]
                      + ["engine.word", "engine.h2d", "engine.replay",
-                        "engine.d2h"] * 2 + ["engine.unpack"])
+                        "engine.d2h"] * 2)
     (_, x0, x1, ex), *inner = evs
     assert len({st["call"] for *_, st in evs}) == 1
     assert all(x0 <= s and e <= x1 for _, s, e, _ in inner)
-    assert inner[0][3] == {"call": ex["call"], "words": 2, "crossbars": 64}
-    assert inner[-1][3] == {"call": ex["call"], "words": 2}
     words = [i for i, ev in enumerate(inner) if ev[0] == "engine.word"]
     for w, i in enumerate(words):
         _, w0, w1, st = inner[i]
         assert st == {"call": ex["call"], "word": w,
-                      "bytes": 4 * (plan.cols + 1) * (plan.rows + 1)}
+                      "bytes": 32 * plan.rows * plan.cols}
         for _, s, e, child in inner[i + 1:i + 4]:
             assert w0 <= s and e <= w1 and child["word"] == w
+
+
+def test_fault_model_runner_keeps_the_host_pack_spans(tmp_path):
+    """The single-word ``FaultModel`` runner still packs and unpacks on the
+    host: engine.pack (words, crossbars) then engine.unpack, no word
+    spans, same call id as engine.execute."""
+    from repro.device.faults import FaultModel
+    plan, mems = _batch_of_64()
+    cp = plan.compile()
+    fm = FaultModel(p_switch=1e-3)
+    want = execute(cp, mems[:32], backend="jax", faults=fm, rng=0)  # warm
+    got, evs = _profiled(
+        tmp_path,
+        lambda: execute(cp, mems[:32], backend="jax", faults=fm, rng=0),
+        "engine.")
+    np.testing.assert_array_equal(got.mem, want.mem)
+    assert [name for name, *_ in evs] == ["engine.execute", "engine.pack",
+                                          "engine.unpack"]
+    (_, _, _, ex), (_, _, _, pk), (_, _, _, up) = evs
+    assert pk == {"call": ex["call"], "words": 1, "crossbars": 32}
+    assert up == {"call": ex["call"], "words": 1}
 
 
 def test_engine_fault_run_sets_fault_gauges():

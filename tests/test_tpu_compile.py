@@ -11,6 +11,8 @@ Compiled, at the paper's 1024x1024 / 32-partition geometry:
 * the fused replay body of ``BinaryMatvecPlan(1024, 384)`` (Table I);
 * the unfused scan of ``MatvecPlan(1024, 8, 32)`` (Table I; 158 segments,
   so jax replays it unfused);
+* the per-word program around each of the two (32 crossbars in, pack,
+  replay, unpack, 32 out), with its scratch bytes bounded;
 * the three Pallas kernels at the operand shapes the pallas backend passes
   for the paper's binary matvec, matvec and conv plans;
 * the ``shard_map`` tile runner on a mesh of the four described devices.
@@ -89,6 +91,35 @@ def test_unfused_scan_matvec(one_chip):
     cp = MatvecPlan(1024, 8, 32, **GEOM).compile()
     assert not jax_fuse_eligible(cp)      # replays on the unfused scan
     jax.jit(jax_unfused_body(cp)).lower(_word(cp, one_chip)).compile()
+
+
+@pytest.mark.parametrize("kind", ["binary_matvec", "matvec"])
+def test_device_word_program_at_paper_geometry(one_chip, kind):
+    """The one program a full word runs: 32 uint8 crossbars in (viewed as
+    uint32), packed, replayed (fused body for the binary matvec, unfused
+    scan for the matvec) and unpacked, 32 crossbars out. Its scratch stays
+    under one uint32 copy of the block, so the pack's shift-and-OR is fused
+    and not materialised."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.engine import (WORD_BITS, device_word_program,
+                                   jax_unfused_body)
+    from repro.core.fused import jax_fused_body
+
+    if kind == "binary_matvec":
+        cp = BinaryMatvecPlan(1024, 384, **GEOM).compile()
+        body = jax_fused_body(cp)
+    else:
+        cp = MatvecPlan(1024, 8, 32, **GEOM).compile()
+        body = jax_unfused_body(cp)
+    block = (WORD_BITS, cp.rows, cp.cols)      # 32 MB of uint8 crossbars
+    x = jax.ShapeDtypeStruct((WORD_BITS, cp.rows, cp.cols // 4), jnp.uint32,
+                             sharding=one_chip)
+    compiled = device_word_program(body, cp.rows, cp.cols).lower(x).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == np.prod(block)
+    assert mem.temp_size_in_bytes < 4 * np.prod(block)
 
 
 def _paper_spec(kind):
