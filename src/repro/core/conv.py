@@ -211,6 +211,16 @@ class ConvPlan(CrossbarPlan):
         from .pallas_exec import conv_spec
         return conv_spec(self)
 
+    def compile(self, validate: bool = True, fuse: bool = True):
+        """As :meth:`CrossbarPlan.compile`; a program that does not depend
+        on K is built here if nothing has built it yet. A K-dependent one
+        (``specialize_kernel``, ``stream_kernel``) needs
+        :meth:`ensure_program` first."""
+        if self.program is None and not (self.specialize
+                                         or self.stream_kernel):
+            self.program = self.build()
+        return super().compile(validate, fuse)
+
     def ensure_program(self, K: np.ndarray) -> Program:
         """(Re)build the program if missing or specialized to a different K."""
         k_dependent = self.specialize or self.stream_kernel

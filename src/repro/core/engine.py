@@ -609,8 +609,23 @@ def _output_like(mem: np.ndarray) -> np.ndarray:
         return out
 
 
+def mode_cycles(cp: CompiledProgram) -> Tuple[int, int, int]:
+    """``(column, row, init)`` cycles of ``cp``'s trace: what one replayed
+    word adds to the ``engine.replay.{col,row,init}_cycles`` counters of
+    :func:`replay_words`. Each runner counts them once, when it is built.
+
+    >>> from repro.core import BinaryMatvecPlan
+    >>> mode_cycles(BinaryMatvecPlan(2, 8, rows=16, cols=64, parts=2)
+    ...             .compile())
+    (74, 1, 1)
+    """
+    n = np.bincount(cp.mode, minlength=3)
+    return int(n[MODE_COL]), int(n[MODE_ROW]), int(n[MODE_INIT])
+
+
 def replay_words(mem: np.ndarray, run, call: Optional[int] = None,
-                 word_args=None) -> np.ndarray:
+                 word_args=None,
+                 modes: Optional[Tuple[int, int, int]] = None) -> np.ndarray:
     """Replay ``mem`` through the word program ``run``
     (:func:`device_word_program`) one packed word of 32 crossbars at a
     time: the host path every multi-word jax runner shares.
@@ -632,7 +647,10 @@ def replay_words(mem: np.ndarray, run, call: Optional[int] = None,
     the previous word's copy and hand this one to the copy threads).
     Counters: ``engine.device_pack.words`` and
     ``engine.device_pack.padded_crossbars`` (``engine.device_pack.traces``
-    counts the word program's compiled widths).
+    counts the word program's compiled widths); given the program's
+    ``modes`` (:func:`mode_cycles`), ``engine.replay.col_cycles``,
+    ``engine.replay.row_cycles`` and ``engine.replay.init_cycles`` grow by
+    its cycles of each mode for every word replayed.
     """
     from concurrent.futures import ThreadPoolExecutor
 
@@ -675,16 +693,21 @@ def replay_words(mem: np.ndarray, run, call: Optional[int] = None,
                 del res, host
         for f in copies:
             f.result()
-    _metrics.counter("engine.device_pack.words").inc(word_count(B))
+    words = word_count(B)
+    _metrics.counter("engine.device_pack.words").inc(words)
     _metrics.counter("engine.device_pack.padded_crossbars").inc(padded)
+    if modes is not None:
+        for name, n in zip(("col", "row", "init"), modes):
+            _metrics.counter(f"engine.replay.{name}_cycles").inc(n * words)
     return out
 
 
 def _build_jax_runner(cp: CompiledProgram):
     run = device_word_program(jax_unfused_body(cp), cp.rows, cp.cols)
+    modes = mode_cycles(cp)
 
     def runner(mem_np: np.ndarray, call: Optional[int] = None) -> np.ndarray:
-        return replay_words(mem_np, run, call)
+        return replay_words(mem_np, run, call, modes=modes)
 
     return runner
 

@@ -298,7 +298,7 @@ def _build_jax_fused(cp: CompiledProgram,
     from jax import lax
 
     from .engine import (BIT_GATES, WORD_BITS, device_word_program,
-                         replay_words)
+                         mode_cycles, replay_words)
 
     sched = schedule_for(cp)
     dt = jnp.dtype(np.uint32)
@@ -489,12 +489,13 @@ def _build_jax_fused(cp: CompiledProgram,
     if body_only:
         return ideal_body
 
+    modes = mode_cycles(cp)
     if not realization:
         run_ideal = device_word_program(ideal_body, cp.rows, cp.cols)
 
         def runner(mem_np: np.ndarray,
                    call: Optional[int] = None) -> np.ndarray:
-            return replay_words(mem_np, run_ideal, call)
+            return replay_words(mem_np, run_ideal, call, modes=modes)
         return runner
 
     def real_body(buf, sa, rxs):
@@ -543,7 +544,8 @@ def _build_jax_fused(cp: CompiledProgram,
             rw = real.narrow(WORD_BITS * w, min(WORD_BITS * (w + 1), B))
             return pack_realization(rw)
 
-        return replay_words(mem_np, run_real, call, word_args)
+        return replay_words(mem_np, run_real, call, word_args,
+                            modes=modes)
     return runner
 
 

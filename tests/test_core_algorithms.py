@@ -127,6 +127,22 @@ def test_conv_kernel_specialization_faster():
     assert fast < base
 
 
+def test_conv_compile_builds_a_kernel_independent_program():
+    """A fresh plan compiles without ``.cycles`` or ``run`` first, to the
+    trace those would have built; a K-dependent plan still needs its K."""
+    fresh = ConvPlan(64, 6, 3, 8).compile()
+    plan = ConvPlan(64, 6, 3, 8)
+    assert plan.cycles == fresh.n_cycles
+    after = plan.compile()
+    assert fresh.stats == after.stats
+    for field in ("mode", "nops", "gate", "dst", "ins", "sel", "init_r",
+                  "init_c", "init_v", "row_masks", "col_masks"):
+        np.testing.assert_array_equal(getattr(fresh, field),
+                                      getattr(after, field))
+    with pytest.raises(AssertionError, match="no program built"):
+        ConvPlan(64, 6, 3, 8, specialize_kernel=True).compile()
+
+
 # -- binary conv -------------------------------------------------------------------
 
 
