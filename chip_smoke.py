@@ -292,7 +292,7 @@ def phase_mesh(rng, devices=4, shape=(4096, 2048), rows=128) -> dict:
 
 
 # the mixed stream's four buckets, four requests each: (kind, m, k, N).
-# Matvec N=32 and conv replay on the unfused scan, which compiles in
+# Matvec N=32 and conv replay on the unfused body, which compiles in
 # seconds; a fused N=8 matvec would take most of a minute per device.
 MIX = (("binary_matvec", 1024, 384, 1), ("binary_matvec", 200, 100, 1),
        ("matvec", 256, 8, 32), ("conv", 64, 8, 8))

@@ -311,8 +311,8 @@ def candidates(cp, B: int, cheap: bool = False
     """Candidate ``(backend, max_batch)`` pairs for a batch width.
 
     ``cheap=True`` (the serving layer's inline tune) drops jax-unfused —
-    it is never competitive on fuse-friendly traces and its per-cycle
-    ``lax.switch`` jit is the most expensive artifact to build.
+    it is never competitive on fuse-friendly traces, and its jit is one
+    more artifact to build.
     """
     from .engine import have_jax
     from .fused import jax_fuse_eligible

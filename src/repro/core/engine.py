@@ -10,10 +10,12 @@ and an unfused (per-cycle) variant:
 * ``jax`` — fused by default for segment-friendly traces: one jitted
   function per (program, word dtype) with mode-specialized per-segment
   ``lax.scan`` chunks and **no** per-cycle ``lax.switch``
-  (``fused.build_jax_fused``). The unfused variant folds the whole trace
-  through a per-cycle ``lax.scan`` + ``lax.switch`` — kept as the fallback
-  for heavily mode-interleaved traces and for ``FaultModel`` injection.
-  Gated: raises cleanly when jax is absent.
+  (``fused.build_jax_fused``). The unfused variant loops over the
+  trace's maximal same-mode runs, each replayed cycle by cycle in a loop
+  specialised to its mode, with no per-cycle ``lax.switch`` either — the
+  fallback for traces of many segments. ``FaultModel`` injection keeps a
+  per-cycle ``lax.scan`` + ``lax.switch``. Gated: raises cleanly when jax
+  is absent.
 
 ``backend`` accepts ``"numpy"``/``"jax"`` (auto: fused when the compiled
 trace carries a schedule) plus the explicit variants ``"numpy-fused"``,
@@ -437,14 +439,38 @@ def _run_numpy_faulty(cp: CompiledProgram, mem: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# JAX executor (lax.scan over the packed trace, uint32 bit-planes)
+# JAX executor (loops over the packed trace, uint32 bit-planes)
 # ---------------------------------------------------------------------------
 
 
+def mode_runs(cp: CompiledProgram) -> np.ndarray:
+    """The maximal same-mode runs of ``cp``'s trace, one int32 row
+    ``(start, end, mode)`` each: cycles ``[start, end)``, all in ``mode``.
+    The unfused jax body replays one run at a time.
+
+    >>> from repro.core import BinaryMatvecPlan
+    >>> mode_runs(BinaryMatvecPlan(2, 8, rows=16, cols=64, parts=2)
+    ...           .compile()).tolist()
+    [[0, 1, 2], [1, 2, 0], [2, 3, 1], [3, 76, 0]]
+    """
+    mode = np.asarray(cp.mode)
+    start = np.flatnonzero(np.r_[mode.size > 0, mode[1:] != mode[:-1]])
+    end = np.r_[start[1:], mode.size][:start.size]
+    return np.stack([start, end, mode[start]], axis=1).astype(np.int32)
+
+
 def _build_jax_body(cp: CompiledProgram):
-    """Un-jitted unfused per-cycle scan ``body(buf) -> buf`` over one packed
+    """Un-jitted unfused body ``body(buf) -> buf`` over one packed
     ``(C+1, R+1)`` uint32 word of the canonical buffer (see
-    :func:`jax_unfused_body`); the runner loops words host-side."""
+    :func:`jax_unfused_body`); the runner loops words host-side.
+
+    One loop goes over the trace's maximal same-mode runs
+    (:func:`mode_runs`). Its body holds one per-cycle loop per mode, and
+    each of them has no trips unless the run is in its mode, so the word
+    keeps one layout for a whole run: a column cycle does not pay for the
+    layout a row cycle wants. (A ``lax.switch`` per cycle forces one
+    layout on every mode, and on a TPU v5e it copies the word twice a
+    cycle.)"""
     import jax.numpy as jnp
     from jax import lax
 
@@ -453,11 +479,12 @@ def _build_jax_body(cp: CompiledProgram):
     ones = dt.type(0xFFFFFFFF)
     row_masks = jnp.asarray(cp.row_masks)
     col_masks = jnp.asarray(cp.col_masks)
+    runs = mode_runs(cp)
     xs = {
-        "mode": jnp.asarray(cp.mode, jnp.int32),
         "gate": jnp.asarray(cp.gate, jnp.int32),
         "dst": jnp.asarray(cp.dst),
-        "ins": jnp.asarray(cp.ins),
+        # one (W*5,) row a cycle: a TPU pads a trailing axis of 5 to 128
+        "ins": jnp.asarray(cp.ins.reshape(cp.n_cycles, W * MAX_FANIN)),
         "sel": jnp.asarray(cp.sel),
         "init_r": jnp.asarray(cp.init_r),
         "init_c": jnp.asarray(cp.init_c),
@@ -472,14 +499,14 @@ def _build_jax_body(cp: CompiledProgram):
         return stacked[gate_ids, iota_w]                               # (W, L)
 
     def col_step(buf, x):
-        g = jnp.take(buf, x["ins"].reshape(-1), axis=0).reshape(W, MAX_FANIN, R1)
+        g = jnp.take(buf, x["ins"], axis=0).reshape(W, MAX_FANIN, R1)
         out = gate_select(x["gate"], tuple(g[:, k] for k in range(MAX_FANIN)))
         mask = row_masks[x["sel"]]                           # (W, R1)
         old = jnp.take(buf, x["dst"], axis=0)
         return buf.at[x["dst"]].set(jnp.where(mask, out, old))
 
     def row_step(buf, x):
-        g = jnp.take(buf, x["ins"].reshape(-1), axis=1) \
+        g = jnp.take(buf, x["ins"], axis=1) \
             .reshape(C1, W, MAX_FANIN).transpose(1, 2, 0)    # (W, 5, C1)
         out = gate_select(x["gate"], tuple(g[:, k] for k in range(MAX_FANIN)))
         mask = col_masks[x["sel"]]                           # (W, C1)
@@ -495,14 +522,27 @@ def _build_jax_body(cp: CompiledProgram):
             buf = jnp.where(region, word, buf)
         return buf
 
-    def step(buf, x):
-        buf = lax.switch(x["mode"], (col_step, row_step, init_step), buf, x)
-        return buf, None
+    def at_cycle(step):
+        def replay(t, buf):
+            return step(buf, {k: lax.dynamic_index_in_dim(a, t, keepdims=False)
+                              for k, a in xs.items()})
+        return replay
+
+    loops = ((MODE_COL, at_cycle(col_step)), (MODE_ROW, at_cycle(row_step)),
+             (MODE_INIT, at_cycle(init_step)))
+    run_table = jnp.asarray(runs)
+
+    def run(r, buf):
+        lo, hi, m = run_table[r, 0], run_table[r, 1], run_table[r, 2]
+        for mode_id, replay in loops:
+            buf = lax.fori_loop(lo, jnp.where(m == mode_id, hi, lo),
+                                replay, buf)
+        return buf
 
     def body(buf0):
-        # modest unroll amortizes the while-loop bookkeeping (~35% on CPU)
-        buf, _ = lax.scan(step, buf0, xs, unroll=4)
-        return buf
+        if not len(runs):           # an empty trace
+            return buf0
+        return lax.fori_loop(0, len(runs), run, buf0)
 
     return body
 
@@ -625,7 +665,8 @@ def mode_cycles(cp: CompiledProgram) -> Tuple[int, int, int]:
 
 def replay_words(mem: np.ndarray, run, call: Optional[int] = None,
                  word_args=None,
-                 modes: Optional[Tuple[int, int, int]] = None) -> np.ndarray:
+                 modes: Optional[Tuple[int, int, int]] = None,
+                 runs: Optional[int] = None) -> np.ndarray:
     """Replay ``mem`` through the word program ``run``
     (:func:`device_word_program`) one packed word of 32 crossbars at a
     time: the host path every multi-word jax runner shares.
@@ -650,7 +691,9 @@ def replay_words(mem: np.ndarray, run, call: Optional[int] = None,
     counts the word program's compiled widths); given the program's
     ``modes`` (:func:`mode_cycles`), ``engine.replay.col_cycles``,
     ``engine.replay.row_cycles`` and ``engine.replay.init_cycles`` grow by
-    its cycles of each mode for every word replayed.
+    its cycles of each mode for every word replayed; given the number of
+    same-mode ``runs`` the unfused body loops over (:func:`mode_runs`),
+    ``engine.replay.mode_runs`` grows by it for every word replayed.
     """
     from concurrent.futures import ThreadPoolExecutor
 
@@ -699,15 +742,18 @@ def replay_words(mem: np.ndarray, run, call: Optional[int] = None,
     if modes is not None:
         for name, n in zip(("col", "row", "init"), modes):
             _metrics.counter(f"engine.replay.{name}_cycles").inc(n * words)
+    if runs is not None:
+        _metrics.counter("engine.replay.mode_runs").inc(runs * words)
     return out
 
 
 def _build_jax_runner(cp: CompiledProgram):
     run = device_word_program(jax_unfused_body(cp), cp.rows, cp.cols)
     modes = mode_cycles(cp)
+    runs = len(mode_runs(cp))
 
     def runner(mem_np: np.ndarray, call: Optional[int] = None) -> np.ndarray:
-        return replay_words(mem_np, run, call, modes=modes)
+        return replay_words(mem_np, run, call, modes=modes, runs=runs)
 
     return runner
 
@@ -894,7 +940,7 @@ def execute(
     back to per-cycle replay otherwise; ``"numpy-fused"``/``"jax-fused"``
     require fusion (attaching a schedule on demand), and
     ``"numpy-unfused"``/``"jax-unfused"`` force the legacy per-cycle paths.
-    The auto jax backend also falls back to the unfused scan for heavily
+    The auto jax backend also falls back to the unfused body for heavily
     mode-interleaved traces (see ``fused.JAX_FUSE_MAX_SEGMENTS``) — fused
     lowering is always *correct*, but jit time grows with segment count.
 

@@ -45,9 +45,10 @@ from .compile import (MAX_FANIN, MODE_COL, MODE_INIT, MODE_ROW,
 
 # jax lowering knobs: cycles per scan chunk, max segment length that is
 # fully unrolled instead of scanned, and the segment-count ceiling above
-# which the auto backend falls back to the unfused per-cycle scan (jit
-# trace/compile time grows with segment count; heavily mode-interleaved
-# programs like the wide convs are better served by the one-switch scan).
+# which the auto backend falls back to the unfused body, one loop over the
+# trace's same-mode runs (jit trace/compile time grows with segment count;
+# heavily mode-interleaved programs like the wide convs are better served
+# by the unfused body).
 CHUNK = 8
 INLINE_MAX = 16
 JAX_FUSE_MAX_SEGMENTS = 64

@@ -15,17 +15,24 @@ one jitted program packs them into the canonical word, replays and unpacks
   this path, nothing on the numpy and ``FaultModel`` paths; every width
   compiled by the served prewarm, none by the traffic after it;
 * the reused output memory: written again only once no array on it is
-  held, from one thread or many.
+  held, from one thread or many;
+* the unfused body's loop over same-mode runs, on run shapes that stress
+  its bounds (one-cycle runs, modes alternating every cycle, row runs
+  first or last, init-only, empty) and under ``mesh_exec``'s vmap, and
+  its ``engine.replay.mode_runs`` counter (runs x words, nothing off it).
 """
 import time
 
 import numpy as np
 import pytest
 
-from repro.core import BinaryMatvecPlan, have_jax
-from repro.core.engine import (WORD_BITS, device_word_program, execute,
-                               padded_width, replay_words, word_count,
-                               word_widths)
+from repro.core import (BinaryMatvecPlan, Crossbar, MatvecPlan,
+                        compile_program, have_jax)
+from repro.core.conv import ConvPlan
+from repro.core.engine import (WORD_BITS, _pack, _unpack, device_word_program,
+                               execute, mode_runs, padded_width,
+                               replay_words, word_count, word_widths)
+from repro.core.isa import GATES, ColOp, InitOp, RowOp
 from repro.device.faults import FaultModel, FaultRealization
 from repro.obs import metrics
 
@@ -239,3 +246,154 @@ def test_output_reuse_is_safe_across_threads(plan_cp):
         sys.setswitchinterval(interval)
     assert not any(t.is_alive() for t in threads)
     assert wrong == []
+
+
+# -- the unfused body's loop over same-mode runs -----------------------------
+
+RUN_GEOM = (32, 64, 4)                 # rows, cols, partitions
+
+
+def _pattern_program(pattern: str, seed: int):
+    """One cycle per letter of ``pattern``: ``c`` column gates, ``r`` row
+    gates (random gates and lines, one op in most partitions), ``i`` an
+    init rectangle."""
+    rng = np.random.default_rng(seed)
+    rows, cols, parts = RUN_GEOM
+    gates = list(GATES)
+
+    def ops(op, span, lines):
+        cyc = []
+        for p in range(parts):
+            if cyc and rng.random() < 0.25:
+                continue
+            g = gates[rng.integers(len(gates))]
+            ar = GATES[g].arity
+            at = rng.choice(span, size=ar + 1, replace=False) + p * span
+            sel = [None, sorted(int(v) for v in
+                                rng.choice(lines, 3, replace=False))][
+                rng.integers(2)]
+            cyc.append(op(g, tuple(int(v) for v in at[:ar]), int(at[ar]),
+                          sel))
+        return cyc
+
+    prog = []
+    for kind in pattern:
+        if kind == "c":
+            prog.append(ops(ColOp, cols // parts, rows))
+        elif kind == "r":
+            prog.append(ops(RowOp, rows // parts, cols))
+        else:
+            prog.append([InitOp(sorted(int(v) for v in
+                                       rng.choice(rows, 5, replace=False)),
+                                slice(0, cols, 3), int(rng.integers(2)))])
+    return prog
+
+
+def _small_plan_program(kind: str):
+    g = dict(rows=32, cols=128, parts=4)
+    plan = {"bmv": lambda: BinaryMatvecPlan(4, 16, **g),
+            "mv": lambda: MatvecPlan(8, 2, 4, **g),
+            "conv": lambda: ConvPlan(16, 4, 3, 4, **g)}[kind]()
+    plan.compile()
+    return plan.program, plan.rows, plan.cols, plan.parts
+
+
+RUN_CASES = {
+    "one-cycle-runs": "circrcic",
+    "alternating": "crcrcrcrcrcr",
+    "starts-with-row": "rrrcccc",
+    "ends-with-row": "iccccrrr",
+    "init-only": "iii",
+    "init-runs": "iicccirrrii",
+    "empty": "",
+    "bmv": None, "mv": None, "conv": None,
+}
+
+
+@pytest.mark.parametrize("case", list(RUN_CASES))
+def test_unfused_run_loop_equals_numpy_and_interpreter(case):
+    """The unfused jax body, one loop over the trace's same-mode runs, gives
+    the interpreter's and ``numpy-unfused``'s memory, cycles and stats, on
+    run shapes that stress its bounds and on small real programs, through
+    the word program with partial words (1, 3 and 17 crossbars)."""
+    pattern = RUN_CASES[case]
+    if pattern is None:
+        prog, rows, cols, parts = _small_plan_program(case)
+    else:
+        prog = _pattern_program(pattern, seed=len(pattern))
+        rows, cols, parts = RUN_GEOM
+    cp = compile_program(prog, rows, cols, parts, parts)
+    start, end, mode = mode_runs(cp).T
+    assert np.array_equal(np.r_[0, end], np.r_[start, cp.n_cycles])
+    assert (mode[1:] != mode[:-1]).all()
+    if pattern is not None:
+        assert len(start) == sum(1 for i, m in enumerate(pattern)
+                                 if i == 0 or m != pattern[i - 1])
+    rng = np.random.default_rng(len(case))
+    mems = (rng.random((17, rows, cols)) < 0.5).astype(np.uint8)
+    xb = Crossbar(rows, cols, parts, parts)
+    for B in (1, 3, 17):
+        want = execute(cp, mems[:B], backend="numpy-unfused")
+        got = execute(cp, mems[:B], backend="jax-unfused")
+        np.testing.assert_array_equal(got.mem, want.mem, err_msg=str(B))
+        assert got.cycles == want.cycles == cp.n_cycles
+        assert got.stats == want.stats
+    for b in (0, 16):
+        xb.mem[:, :] = mems[b]
+        xb.cycles = 0
+        xb.stats = {k: 0 for k in xb.stats}
+        xb.run(prog)
+        np.testing.assert_array_equal(got.mem[b], xb.mem)
+    assert (got.cycles, got.stats) == (xb.cycles, dict(xb.stats))
+
+
+def test_unfused_run_loop_under_vmapped_shard_map():
+    """``mesh_exec`` vmaps the unfused body inside ``shard_map``: the run
+    table's bounds stay unbatched, and every stacked word replays as the
+    word program does (a one-device mesh on the CPU)."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec
+
+    from repro.distributed import mesh_exec
+
+    rows, cols, parts = RUN_GEOM
+    cp = compile_program(_pattern_program("iccrrcrcccri", seed=5), rows,
+                         cols, parts, parts)
+    rng = np.random.default_rng(9)
+    mems = (rng.random((70, rows, cols)) < 0.5).astype(np.uint8)
+    spec = PartitionSpec(mesh_exec.TILE_AXIS, None, None)
+    fn = mesh_exec._sharded_runner(cp, mesh_exec.tile_mesh(1), "unfused",
+                                   spec)
+    got = _unpack(np.asarray(fn(jnp.asarray(_pack(mems)))), 70, rows, cols)
+    want = execute(cp, mems, backend="numpy-unfused").mem
+    np.testing.assert_array_equal(got, want)
+
+
+MODE_RUN_CASES = {"unfused-2-words": ("conv", "jax-unfused", 33, 2),
+                  "unfused-1-word": ("conv", "jax-unfused", 32, 1),
+                  "numpy": ("conv", "numpy", 33, 0),
+                  "fused": ("bmv", "jax-fused", 40, 0),
+                  "realization": ("bmv", "realization", 40, 0)}
+
+
+@pytest.mark.parametrize("case", list(MODE_RUN_CASES))
+def test_mode_runs_counter_grows_by_runs_per_word(case):
+    """``engine.replay.mode_runs`` grows by the program's same-mode runs for
+    every word the unfused jax body replays, and stays put on numpy and on
+    the fused runners, which have no run loop."""
+    kind, backend, B, words = MODE_RUN_CASES[case]
+    prog, rows, cols, parts = _small_plan_program(kind)
+    cp = compile_program(prog, rows, cols, parts, parts)
+    runs = len(mode_runs(cp))
+    assert 3 <= runs < cp.n_cycles
+    mems = np.zeros((B, rows, cols), np.uint8)
+    faults = None
+    if backend == "realization":
+        backend = "jax-fused"
+        faults = FaultRealization.sample(FaultModel(p_switch=0.01), B, rows,
+                                         cols, cp.n_cycles, cp.W, cp.I, rng=1)
+    counter = metrics.counter("engine.replay.mode_runs")
+    col = metrics.counter("engine.replay.col_cycles")
+    execute(cp, mems, backend=backend, faults=faults)
+    assert counter.value == words * runs
+    assert col.value > 0 or backend == "numpy"   # the word loop did replay

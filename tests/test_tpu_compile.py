@@ -9,9 +9,10 @@ anything; ``chip_smoke.py`` is the run on the chip.
 Compiled, at the paper's 1024x1024 / 32-partition geometry:
 
 * the fused replay body of ``BinaryMatvecPlan(1024, 384)`` (Table I);
-* the unfused scan of ``MatvecPlan(1024, 8, 32)`` (Table I; 158 segments,
-  so jax replays it unfused);
-* the per-word program around each of the two (32 crossbars in, pack,
+* the unfused body of ``MatvecPlan(1024, 8, 32)`` (Table I; 158 segments,
+  so jax replays it unfused), and of ``MatvecPlan`` and ``ConvPlan(1024, 8,
+  3, 32)`` (Table II) with no copy of the whole word in any per-cycle loop;
+* the per-word program around each of the three (32 crossbars in, pack,
   replay, unpack, 32 out), with its scratch bytes bounded;
 * the three Pallas kernels at the operand shapes the pallas backend passes
   for the paper's binary matvec, matvec and conv plans;
@@ -22,7 +23,9 @@ one process may load the TPU library, and under pytest-xdist every worker
 imports this file. The persistent compilation cache is off around these
 compiles (an entry written for a described chip cannot be read back).
 """
+import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -93,13 +96,72 @@ def test_unfused_scan_matvec(one_chip):
     jax.jit(jax_unfused_body(cp)).lower(_word(cp, one_chip)).compile()
 
 
-@pytest.mark.parametrize("kind", ["binary_matvec", "matvec"])
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition|branch_computations"
+                     r"|called_computations)=\{?([%\w.\-, ]+)\}?")
+
+
+def _reachable(comps, root):
+    """``root`` and every computation it calls, transitively."""
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            for m in _CALLED.finditer(line):
+                todo += [n.strip() for n in m.group(1).split(",")]
+    return seen
+
+
+@functools.lru_cache(maxsize=None)
+def _unfused_program(kind):
+    """The compiled Table I matvec or Table II conv, built once a module."""
+    if kind == "matvec":
+        return MatvecPlan(1024, 8, 32, **GEOM).compile()
+    return ConvPlan(1024, 8, 3, 32, **GEOM).compile()
+
+
+@pytest.mark.parametrize("kind", ["matvec", "conv"])
+def test_unfused_body_per_cycle_loops_hold_no_word_copy(one_chip, kind):
+    """The unfused body at the paper's geometry, compiled for the chip: no
+    per-cycle loop (a while loop whose body holds no other while loop)
+    copies the whole word, so a column cycle never pays for the layout a
+    row cycle wants; the copies between layouts sit in the per-run loop."""
+    import jax
+
+    from repro.core.engine import jax_unfused_body, mode_runs
+    from repro.core.fused import jax_fuse_eligible
+    from repro.launch.hlo_analysis import parse_computations
+
+    cp = _unfused_program(kind)
+    assert not jax_fuse_eligible(cp)
+    text = jax.jit(jax_unfused_body(cp)).lower(_word(cp, one_chip)) \
+        .compile().as_text()
+    comps = parse_computations(text)
+    word = rf"u32\[{cp.cols + 1},{cp.rows + 1}\]\S* copy(-start)?\("
+    loops = {}
+    for line in text.splitlines():
+        if " while(" in line:
+            body = re.search(r"body=([%\w.\-]+)", line).group(1)
+            inner = _reachable(comps, body)
+            nested = any(" while(" in ln for c in inner for ln in comps[c])
+            copies = sum(bool(re.search(word, ln))
+                         for c in inner for ln in comps[c])
+            loops[body] = (nested, copies)
+    per_cycle = [b for b, (nested, _) in loops.items() if not nested]
+    assert all(loops[b][1] == 0 for b in per_cycle), loops
+    assert len(per_cycle) >= 2 and len(mode_runs(cp)) > 2
+    assert any(copies for nested, copies in loops.values() if nested)
+
+
+@pytest.mark.parametrize("kind", ["binary_matvec", "matvec", "conv"])
 def test_device_word_program_at_paper_geometry(one_chip, kind):
     """The one program a full word runs: 32 uint8 crossbars in (viewed as
     uint32), packed, replayed (fused body for the binary matvec, unfused
-    scan for the matvec) and unpacked, 32 crossbars out. Its scratch stays
-    under one uint32 copy of the block, so the pack's shift-and-OR is fused
-    and not materialised."""
+    body for the matvec and the conv) and unpacked, 32 crossbars out. Its
+    scratch stays under one uint32 copy of the block, so the pack's
+    shift-and-OR is fused and not materialised."""
     import jax
     import jax.numpy as jnp
 
@@ -111,7 +173,7 @@ def test_device_word_program_at_paper_geometry(one_chip, kind):
         cp = BinaryMatvecPlan(1024, 384, **GEOM).compile()
         body = jax_fused_body(cp)
     else:
-        cp = MatvecPlan(1024, 8, 32, **GEOM).compile()
+        cp = _unfused_program(kind)
         body = jax_unfused_body(cp)
     block = (WORD_BITS, cp.rows, cp.cols)      # 32 MB of uint8 crossbars
     x = jax.ShapeDtypeStruct((WORD_BITS, cp.rows, cp.cols // 4), jnp.uint32,
