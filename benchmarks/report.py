@@ -35,7 +35,7 @@ def load():
     cells = {}
     for f in glob.glob(str(RESULTS_DIR / "*.json")):
         d = json.load(open(f))
-        if "arch" not in d:          # e.g. results/tunings.json
+        if "arch" not in d:
             continue
         cells[(d["arch"], d["shape"], d.get("mesh", "?"))] = d
     return cells
@@ -218,59 +218,6 @@ def bench_delta_table() -> list:
     if printed_header and not warnings:
         print("\nno regressions above "
               f"{REGRESSION_PCT:.0f}% and no deterministic-metric drift")
-    return warnings
-
-
-AUTO_SLACK_PCT = 10.0
-
-
-def auto_vs_fixed_table() -> list:
-    """Flag engine ``auto`` rows slower than the best fixed variant.
-
-    The autotuner's whole contract is that ``backend="auto"`` never loses to
-    a spelling the caller could have picked by hand. Engine metrics group by
-    their prefix before the trailing ``_<backend>`` token; within a group
-    the ``_auto`` row must be within ``AUTO_SLACK_PCT`` percent of the
-    fastest fixed variant (interp rows are excluded — auto never resolves
-    to the interpreter). Returns the WARNING strings (also printed).
-    """
-    p = ROOT / "BENCH_engine.json"
-    if not p.exists():
-        return []
-    suffixes = ("numpy_unfused", "jax_unfused", "numpy", "jax", "auto")
-    groups: dict = {}
-    for m in json.load(open(p))["metrics"]:
-        for be in suffixes:              # longest-first: *_numpy_unfused
-            if m["name"].endswith("_" + be):
-                base = m["name"][:-(len(be) + 1)]
-                groups.setdefault(base, {})[be] = m["value"]
-                break
-    warnings = []
-    rows = []
-    for base, bes in sorted(groups.items()):
-        if "auto" not in bes or len(bes) < 2:
-            continue
-        fixed = {be: v for be, v in bes.items() if be != "auto"}
-        best_be, best = min(fixed.items(), key=lambda kv: kv[1])
-        slack = (bes["auto"] - best) / best * 100
-        rows.append(f"| {base} | {best_be} | {best:g} | {bes['auto']:g} | "
-                    f"{slack:+.1f}% |")
-        if slack > AUTO_SLACK_PCT:
-            warnings.append(
-                f"WARNING: {base}_auto is {slack:+.1f}% slower than the best "
-                f"fixed variant {best_be} ({best:g} vs {bes['auto']:g} us) — "
-                f"the tunings table resolved a losing backend")
-    if rows:
-        print("\n### Auto backend vs best fixed variant\n")
-        print("| metric group | best fixed | us | auto us | auto slack |")
-        print("|---|---|---|---|---|")
-        for r in rows:
-            print(r)
-        for w in warnings:
-            print(w)
-        if not warnings:
-            print(f"\nevery auto row within {AUTO_SLACK_PCT:.0f}% of the "
-                  f"best fixed variant")
     return warnings
 
 
@@ -529,7 +476,6 @@ def main():
     print("## §Perf trajectory (BENCH_*.json)\n")
     bench_table()
     bench_delta_table()
-    auto_vs_fixed_table()
     sharded_scaling_table()
     slo_table()
     print("\n## §Dry-run\n")

@@ -102,29 +102,14 @@ def bench_engine(quick=False):
     Reports the single-array case, the batched multi-instance case (the
     engine's bit-plane packing simulates up to 64 crossbars per word), and
     the tiled multi-crossbar matvec that exceeds a single 1024x1024 array.
-    The auto ``numpy``/``jax`` backends replay the fused macro-op schedule;
+    The plain ``numpy``/``jax`` backends replay the fused macro-op schedule;
     the ``*_unfused`` rows keep the per-cycle executors measured so the
     fusion win (and any regression) stays visible across PRs. Cycle counts
     are asserted identical across every backend — fusion must never touch
     the latency model.
-
-    Every fixed-variant timing is also folded into the autotuner's tunings
-    table (``results/tunings.json`` unless ``$MATPIM_TUNINGS`` points
-    elsewhere), and an ``auto`` row per batch width runs ``backend="auto"``
-    against that table — ``benchmarks/report.py`` flags any auto row slower
-    than the best fixed variant, which would mean the tuner mis-resolved.
     """
-    import os
-
     import numpy as np
     from repro.core import BinaryMatvecPlan, have_jax, tiled_binary_matvec
-    from repro.core import autotune as at
-    from repro.core.fused import jax_fuse_eligible
-
-    os.environ.setdefault(at.TUNINGS_ENV,
-                          str(ROOT / "results" / "tunings.json"))
-    at.reset_default_table()            # re-read the env-selected path
-    table = at.get_default_table()
 
     rng = np.random.default_rng(0)
     m, n = (256, 128) if quick else (1024, 384)
@@ -135,24 +120,11 @@ def bench_engine(quick=False):
     cycles = len(plan.program)
     assert cp.schedule.n_cycles == cycles
     segs = cp.schedule.n_segments
-    pkey = at.program_key(cp)
-    jf = "jax-fused" if have_jax() and jax_fuse_eligible(cp) else "jax-unfused"
-    # concrete variant each bench spelling resolves to (for the table)
-    concrete = {"numpy": "numpy-fused", "numpy_unfused": "numpy-unfused",
-                "jax": jf, "jax_unfused": "jax-unfused"}
     # fused jax measured BEFORE unfused: the unfused runner's device
     # buffers/executables bloat the XLA arena and skew later rows on this
     # memory-tight container
     backends = ("numpy_unfused", "numpy") + (
         ("jax", "jax_unfused") if have_jax() else ())
-
-    def auto_row(name: str, B: int, t_base: float, base_name: str,
-                 timer) -> None:
-        choice, mb, src = at.resolve_auto(cp, B, table=table)
-        t = timer()
-        mbs = f"@{mb}" if mb else ""
-        _rec(name, t, f"{base_name}={t_base/t:.1f};chosen={choice}{mbs};"
-                      f"source={src};cycles={cycles}")
 
     def run_be(be):
         _, _, c = plan.run(A, x, backend=be.replace("_unfused", "-unfused"))
@@ -163,14 +135,9 @@ def bench_engine(quick=False):
          f"backend=interp;cycles={cycles}")
     for be in backends:
         t = _best_of(lambda: run_be(be), n=5, warmup=1)
-        table.observe(pkey, 1, concrete[be], t)
         extra = f";segments={segs}" if "unfused" not in be else ""
         _rec(f"engine/binary_mv_{m}x{n}_{be}", t,
              f"speedup_vs_interp={t_int/t:.1f};cycles={cycles}{extra}")
-    auto_row(f"engine/binary_mv_{m}x{n}_auto", 1, t_int,
-             "speedup_vs_interp",
-             lambda: _best_of(lambda: plan.run(A, x, backend="auto"),
-                              n=5, warmup=1))
 
     # batched: B independent crossbar instances in one engine call
     B = 8 if quick else 32
@@ -191,18 +158,12 @@ def bench_engine(quick=False):
     for be in backends:
         t = _best_of(lambda: plan.execute_batch(
             mems, backend=be.replace("_unfused", "-unfused")), n=5, warmup=1)
-        table.observe(pkey, at.batch_bucket(B), concrete[be], t)
         _rec(f"engine/binary_mv_batch{B}_{be}", t,
              f"speedup_vs_interp={t_int/t:.1f};cycles={cycles}")
-    auto_row(f"engine/binary_mv_batch{B}_auto", B, t_int,
-             "speedup_vs_interp",
-             lambda: _best_of(lambda: plan.execute_batch(
-                 mems, backend="auto"), n=5, warmup=1))
 
     # wide batches (past one jax word): the regime where fusion historically
     # LOST to per-cycle numpy — measured vs per-cycle numpy as reference (the
-    # interpreter would dominate the bench), plus the auto row the tunings
-    # table must keep at >= the best fixed variant
+    # interpreter would dominate the bench)
     cp._caches.pop("jax_runner", None)   # release the unfused jit + buffers
     if not quick:
         for B in (64, 128):
@@ -212,28 +173,14 @@ def bench_engine(quick=False):
                                rng.choice([-1, 1], size=n))
             t_ref = _best_of(lambda: plan.execute_batch(
                 mems, backend="numpy-unfused"), n=2, warmup=1)
-            table.observe(pkey, at.batch_bucket(B), "numpy-unfused", t_ref)
             _rec(f"engine/binary_mv_batch{B}_numpy_unfused", t_ref,
                  f"backend=numpy-unfused;cycles={cycles}")
             for be in ("numpy",) + (("jax",) if have_jax() else ()):
                 t = _best_of(lambda: plan.execute_batch(mems, backend=be),
                              n=2, warmup=1)
-                table.observe(pkey, at.batch_bucket(B), concrete[be], t)
                 _rec(f"engine/binary_mv_batch{B}_{be}", t,
                      f"speedup_vs_numpy_unfused={t_ref/t:.1f};"
                      f"cycles={cycles}")
-            # span-chunking candidate (word-width chunks of the wide batch):
-            # measured so the table can prefer it when it wins
-            t_ch = _best_of(lambda: plan.execute_batch(
-                mems, backend="numpy-unfused",
-                max_batch=at.CHUNK_BATCH), n=2, warmup=1)
-            table.observe(pkey, at.batch_bucket(B), "numpy-unfused", t_ch,
-                          max_batch=at.CHUNK_BATCH)
-            auto_row(f"engine/binary_mv_batch{B}_auto", B, t_ref,
-                     "speedup_vs_numpy_unfused",
-                     lambda: _best_of(lambda: plan.execute_batch(
-                         mems, backend="auto"), n=2, warmup=1))
-    table.save()
 
     # tiled scale-out: (M, K) exceeding a single 1024x1024 crossbar
     M, K = (2048, 768) if quick else (4096, 2048)

@@ -11,12 +11,8 @@ Public API:
     BinaryConvPlan         — §III-C binary convolution
     tiling                 — multi-crossbar scale-out (tiled matvec / conv)
     latency                — Table I/II regeneration + published numbers
-    autotune               — batch-aware backend tuner (tunings table)
     pallas_exec            — "pallas" backend: traces on repro.kernels
 """
-from .autotune import (TuningEntry, TuningTable, autotune_execute,
-                       batch_bucket, get_default_table, program_key,
-                       resolve_auto)
 from .binary_conv import BinaryConvPlan, matpim_binary_conv2d
 from .binary_matvec import (BinaryMatvecPlan, NaiveBinaryMatvecPlan,
                             matpim_binary_matvec)
@@ -37,11 +33,9 @@ __all__ = [
     "Crossbar", "CrossbarPlan", "EngineResult", "FusedSchedule",
     "MatvecPlan", "NaiveBinaryMatvecPlan", "SchedulingError", "Segment",
     "TiledBinaryMatvec", "TiledConv2d", "TiledMatvec", "TiledResult",
-    "TuningEntry", "TuningTable", "autotune_execute", "available_backends",
-    "batch_bucket", "compile_program", "decode_uint", "encode_uint",
-    "execute", "fuse_program", "get_default_table", "have_jax",
-    "matpim_binary_conv2d", "matpim_binary_matvec", "matpim_conv2d",
-    "matpim_matvec", "parse_backend", "program_key", "resolve_auto",
-    "tiled_binary_conv2d", "tiled_binary_matvec", "tiled_conv2d",
-    "tiled_matvec",
+    "available_backends", "compile_program", "decode_uint", "encode_uint",
+    "execute", "fuse_program", "have_jax", "matpim_binary_conv2d",
+    "matpim_binary_matvec", "matpim_conv2d", "matpim_matvec",
+    "parse_backend", "tiled_binary_conv2d", "tiled_binary_matvec",
+    "tiled_conv2d", "tiled_matvec",
 ]

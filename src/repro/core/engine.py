@@ -17,8 +17,8 @@ and an unfused (per-cycle) variant:
   per-cycle ``lax.scan`` + ``lax.switch``. Gated: raises cleanly when jax
   is absent.
 
-``backend`` accepts ``"numpy"``/``"jax"`` (auto: fused when the compiled
-trace carries a schedule) plus the explicit variants ``"numpy-fused"``,
+``backend`` accepts ``"numpy"``/``"jax"`` (the body picked from the trace
+by :func:`_variant`) plus the explicit variants ``"numpy-fused"``,
 ``"numpy-unfused"``, ``"jax-fused"``, ``"jax-unfused"``.
 
 Canonical packed-word layout
@@ -108,52 +108,53 @@ def have_jax() -> bool:
 def available_backends() -> tuple:
     """The real set of backends ``execute`` accepts for compiled traces.
 
-    ``"auto"`` resolves per ``(program key, batch bucket)`` from the tunings
-    table (measured) or a conservative heuristic; ``"numpy"``/``"jax"`` pick
-    fused-vs-unfused from the trace alone; the ``-fused``/``-unfused`` forms
-    force a variant; ``"pallas"`` lowers eligible traces onto the
-    ``repro.kernels`` Pallas kernels and falls back otherwise.
-    ``CrossbarPlan`` methods additionally accept ``"interp"`` (the uncompiled
-    interpreter), which is plan-level only.
+    ``"numpy"`` is the host reference and ``"jax"`` the device path; each
+    picks its fused or unfused body from the trace (see ``execute``), and
+    the ``-fused``/``-unfused`` forms force one. ``"pallas"`` computes
+    eligible plans' results on the ``repro.kernels`` Pallas kernels
+    without replaying the trace, and falls back to the replay otherwise.
+    ``CrossbarPlan`` methods additionally accept ``"interp"`` (the
+    uncompiled interpreter), which is plan-level only.
 
     >>> bs = available_backends()
-    >>> ("auto" in bs, "numpy-fused" in bs, "numpy-unfused" in bs)
+    >>> ("numpy" in bs, "numpy-fused" in bs, "numpy-unfused" in bs)
     (True, True, True)
     >>> ("jax" in bs) == ("pallas" in bs)  # both need jax
     True
     """
-    base = ("auto", "numpy", "numpy-fused", "numpy-unfused")
+    base = ("numpy", "numpy-fused", "numpy-unfused")
     if have_jax():
         base += ("jax", "jax-fused", "jax-unfused", "pallas")
     return base
 
 
 def parse_backend(backend: str) -> tuple:
-    """``backend`` → ``(base, variant)`` with base in
-    {auto, numpy, jax, pallas} and variant in {auto, fused, unfused}.
+    """``backend`` → ``(base, variant)`` with base in {numpy, jax, pallas}
+    and variant in {None, fused, unfused}; ``None`` leaves the choice to
+    the trace (see ``execute``).
 
     >>> parse_backend("numpy"), parse_backend("jax-fused")
-    (('numpy', 'auto'), ('jax', 'fused'))
-    >>> parse_backend("auto"), parse_backend("pallas")
-    (('auto', 'auto'), ('pallas', 'auto'))
+    (('numpy', None), ('jax', 'fused'))
+    >>> parse_backend("pallas")
+    ('pallas', None)
     >>> parse_backend("interp")
     Traceback (most recent call last):
         ...
     ValueError: unknown engine backend 'interp'; compiled traces support \
-'auto', 'numpy', 'numpy-fused', 'numpy-unfused', 'jax', 'jax-fused', \
+'numpy', 'numpy-fused', 'numpy-unfused', 'jax', 'jax-fused', \
 'jax-unfused', 'pallas' ('interp' is plan-level only: use \
 CrossbarPlan.execute)
     """
-    base, variant = backend, "auto"
+    base, variant = backend, None
     if backend.endswith("-fused"):
         base, variant = backend[:-len("-fused")], "fused"
     elif backend.endswith("-unfused"):
         base, variant = backend[:-len("-unfused")], "unfused"
     if base not in ("numpy", "jax") and not (
-            base in ("auto", "pallas") and variant == "auto"):
+            base == "pallas" and variant is None):
         # enumerate the full spelling set, not just what this host can run:
         # a clear error beats hiding 'jax'/'pallas' on a cpu-only box
-        known = ("'auto', 'numpy', 'numpy-fused', 'numpy-unfused', 'jax', "
+        known = ("'numpy', 'numpy-fused', 'numpy-unfused', 'jax', "
                  "'jax-fused', 'jax-unfused', 'pallas'")
         raise ValueError(
             f"unknown engine backend {backend!r}; compiled traces support "
@@ -179,10 +180,6 @@ class EngineResult:
 # executor (numpy, jax, mesh, pallas operand packing) shares it; batches
 # wider than one word grow the leading W axis instead of the word dtype.
 WORD_BITS = 32
-
-# legacy alias (the constant predates the canonical layout; importers treat
-# it as "the jax chunk width", which is still the word width)
-JAX_WORD_BITS = WORD_BITS
 
 
 def word_count(B: int) -> int:
@@ -913,36 +910,47 @@ def execute(
     max_batch: Optional[int] = None,
     faults=None,
     rng=None,
-    tunings=None,
     mesh=None,
 ) -> EngineResult:
     """Replay ``cp`` over a batch of crossbars.
 
     Telemetry: every call runs under a ``span("engine.execute")`` (no-op
     unless tracing is enabled) and publishes into the ``repro.obs`` metrics
-    registry — ``engine.execute.calls[.<label>]`` counters, a per-resolved-
-    backend ``engine.execute.wall_us.<label>`` histogram, and fault-model
-    gauges (``engine.fault.p_*``) when a non-ideal :class:`FaultModel` is
-    supplied. The label is the result's ``backend`` field with any ``@mb``
-    chunking suffix stripped (e.g. ``auto:jax-fused``).
+    registry — ``engine.execute.calls[.<label>]`` counters, a per-label
+    ``engine.execute.wall_us.<label>`` histogram, and fault-model gauges
+    (``engine.fault.p_*``) when a non-ideal :class:`FaultModel` is
+    supplied. The label is the result's ``backend`` field (e.g. ``jax``,
+    ``pallas:fallback-jax``, ``jax+mesh4``).
 
     ``mem`` is ``(B, rows, cols)`` (or ``(rows, cols)`` for B=1) uint8 initial
     state; the input is not mutated. Any batch packs into the canonical
     ``(W, cols+1, rows+1)`` uint32 layout (``W = ceil(B/32)``) and runs in
-    one executor call; only ``max_batch`` (span chunking from the autotuner)
-    and ``FaultModel`` runs — which keep the historic chunk widths (64 numpy
-    / 32 jax) so same-seed Monte-Carlo draws stay bit-identical — split the
-    batch. Every chunk runs the identical program, so the reported cycle
-    count (the *parallel* latency of B independent arrays) is unchanged.
+    one executor call; only ``max_batch`` and ``FaultModel`` runs — which
+    keep the historic chunk widths (64 numpy / 32 jax) so same-seed
+    Monte-Carlo draws stay bit-identical — split the batch. Every chunk
+    runs the identical program, so the reported cycle count (the *parallel*
+    latency of B independent arrays) is unchanged.
 
-    ``backend`` selects the executor: ``"numpy"``/``"jax"`` use the fused
-    macro-op schedule when ``cp`` carries one (the compile default) and fall
-    back to per-cycle replay otherwise; ``"numpy-fused"``/``"jax-fused"``
-    require fusion (attaching a schedule on demand), and
-    ``"numpy-unfused"``/``"jax-unfused"`` force the legacy per-cycle paths.
-    The auto jax backend also falls back to the unfused body for heavily
-    mode-interleaved traces (see ``fused.JAX_FUSE_MAX_SEGMENTS``) — fused
-    lowering is always *correct*, but jit time grows with segment count.
+    ``backend`` selects the executor. ``"numpy"`` is the host reference and
+    ``"jax"`` the device path; which of its two bodies runs — the fused
+    macro-op schedule or the unfused per-cycle replay — is decided from the
+    trace and the fault argument by one rule (:func:`_variant`):
+
+    * ``"-fused"``/``"-unfused"`` are obeyed (a fused run attaches the
+      schedule on demand);
+    * a ``FaultRealization`` runs fused, the only jax path that takes one;
+    * a trace compiled with ``fuse=False`` (no schedule) runs unfused;
+    * on jax, a ``FaultModel`` (the PRNG-threaded unfused path) or a trace
+      past ``fused.JAX_FUSE_MAX_SEGMENTS`` segments (jit time grows with
+      segment count) runs unfused;
+    * everything else runs fused.
+
+    ``backend="pallas"`` computes the result of traces that carry a
+    plan-attached ``pallas_spec`` (binary matvec, encoded matvec, conv) on
+    the ``repro.kernels`` Pallas kernels — interpret-mode off-TPU, Mosaic
+    on TPU — without replaying the trace, and falls back to jax/numpy for
+    ineligible programs or fault runs (``backend`` field
+    ``"pallas:fallback-<base>"``).
 
     ``faults`` selects a device model: a
     :class:`repro.device.faults.FaultModel` (each crossbar draws an
@@ -950,26 +958,9 @@ def execute(
     init disturb — seeded from ``rng``: ``None``/seed/Generator) or an
     explicit :class:`repro.device.faults.FaultRealization` whose per-cycle
     masks replay bit-identically on every backend that accepts them.
-    Support matrix: numpy paths take both; the jax auto path serves a
-    ``FaultModel`` through the unfused PRNG-threaded scan (unchanged
-    behavior) and a ``FaultRealization`` through the fused runner.
     The fault machinery runs even for the ideal all-zero model —
     bit-identity with ``faults=None`` is a property-tested guarantee, not a
     shortcut — and never adds cycles: faults perturb state, not schedules.
-
-    Two meta-backends layer on top of the four concrete paths.
-    ``backend="auto"`` resolves a concrete backend (and optionally a
-    span-chunking ``max_batch``) per ``(program key, batch bucket)`` from
-    the autotuner's tunings table — ``tunings`` (a
-    :class:`repro.core.autotune.TuningTable`) overrides the process default
-    — falling back to a conservative heuristic when nothing is measured;
-    the result's ``backend`` field records the choice as
-    ``"auto:<resolved>"``. ``backend="pallas"`` lowers traces that carry a
-    plan-attached ``pallas_spec`` (binary matvec, encoded matvec, conv)
-    onto the ``repro.kernels`` Pallas kernels — interpret-mode off-TPU,
-    Mosaic on TPU — and transparently falls back to jax/numpy for
-    ineligible programs or fault runs (``backend`` field
-    ``"pallas:fallback-<base>"``).
 
     ``mesh`` (or an ambient ``distributed.sharding.use_mesh``) shards a
     fault-free jax batch over the mesh's ``tiles`` axis (label suffix
@@ -982,11 +973,11 @@ def execute(
         mesh = _ambient_mesh()
     call = next(_CALLS)
     with _span("engine.execute", backend=backend, call=call) as sp:
-        res = _execute_impl(cp, mem, backend, max_batch, faults, rng, tunings,
-                            mesh, call)
+        res = _execute_impl(cp, mem, backend, max_batch, faults, rng, mesh,
+                            call)
         sp.set(resolved=res.backend, cycles=res.cycles)
     wall_us = (time.perf_counter() - t0) * 1e6
-    label = res.backend.split("@", 1)[0]
+    label = res.backend
     _metrics.counter("engine.execute.calls").inc()
     _metrics.counter(f"engine.execute.calls.{label}").inc()
     _metrics.histogram(f"engine.execute.wall_us.{label}").observe(wall_us)
@@ -1011,6 +1002,25 @@ def _mesh_declined(devices: int, reason: str) -> None:
                   f"on one device", MeshDeclinedWarning, stacklevel=4)
 
 
+def _variant(cp: CompiledProgram, base: str, variant: Optional[str],
+             faults) -> str:
+    """The one rule that picks the fused or unfused body (see ``execute``).
+
+    ``variant`` is the caller's explicit ``-fused``/``-unfused`` or ``None``;
+    everything else is read off the trace and the fault argument.
+    """
+    from .fused import jax_fuse_eligible
+    if variant is not None:
+        return variant
+    if isinstance(faults, FaultRealization):
+        return "fused"               # the only faulty jax path; fine on numpy
+    if cp.schedule is None:
+        return "unfused"
+    if base == "jax" and (faults is not None or not jax_fuse_eligible(cp)):
+        return "unfused"
+    return "fused"
+
+
 def _execute_impl(
     cp: CompiledProgram,
     mem: np.ndarray,
@@ -1018,12 +1028,11 @@ def _execute_impl(
     max_batch: Optional[int],
     faults,
     rng,
-    tunings,
     mesh=None,
     call: Optional[int] = None,
 ) -> EngineResult:
     from .fused import (build_jax_fused, build_jax_fused_real,
-                        jax_fuse_eligible, run_numpy_fused, schedule_for)
+                        run_numpy_fused, schedule_for)
 
     squeeze = mem.ndim == 2
     if squeeze:
@@ -1049,16 +1058,7 @@ def _execute_impl(
 
     base, variant = parse_backend(backend)
     label = backend
-    if base == "auto":
-        from .autotune import resolve_auto
-        resolved, mb, _src = resolve_auto(cp, mem.shape[0], faults=faults,
-                                          table=tunings, topo=topo)
-        base, variant = parse_backend(resolved)
-        if max_batch is None and mb is not None:
-            max_batch = mb
-        label = (f"auto:{resolved}@{mb}" if mb is not None
-                 else f"auto:{resolved}")
-    elif base == "pallas":
+    if base == "pallas":
         from .pallas_exec import pallas_eligible, run_pallas
         if pallas_eligible(cp, faults):
             if topo > 1:
@@ -1068,7 +1068,7 @@ def _execute_impl(
                 out = out[0]
             return EngineResult(mem=out, cycles=cp.n_cycles,
                                 stats=dict(cp.stats), backend="pallas")
-        base, variant = ("jax", "auto") if have_jax() else ("numpy", "auto")
+        base = "jax" if have_jax() else "numpy"
         label = f"pallas:fallback-{base}"
     if base == "jax" and not have_jax():
         raise RuntimeError("jax backend requested but jax is not installed")
@@ -1082,16 +1082,7 @@ def _execute_impl(
     if max_batch:
         step = min(step, max(1, int(max_batch)))
 
-    if variant == "auto":
-        if isinstance(faults, FaultRealization):
-            variant = "fused"        # the only faulty jax path; fine on numpy
-        elif cp.schedule is None:
-            variant = "unfused"
-        elif base == "jax":
-            variant = ("unfused" if faults is not None
-                       or not jax_fuse_eligible(cp) else "fused")
-        else:
-            variant = "fused"
+    variant = _variant(cp, base, variant, faults)
     if variant == "fused":
         schedule_for(cp)             # attach on demand for fuse=False traces
     if base == "jax":
@@ -1103,7 +1094,7 @@ def _execute_impl(
         if variant == "unfused" and isinstance(faults, FaultRealization):
             raise ValueError(
                 "jax-unfused does not take a FaultRealization; use 'jax' "
-                "(auto) or 'jax-fused'")
+                "or 'jax-fused'")
     if isinstance(faults, FaultRealization) and faults.batch != B:
         raise ValueError(
             f"FaultRealization batch {faults.batch} != memory batch {B}; "

@@ -45,10 +45,10 @@ from .compile import (MAX_FANIN, MODE_COL, MODE_INIT, MODE_ROW,
 
 # jax lowering knobs: cycles per scan chunk, max segment length that is
 # fully unrolled instead of scanned, and the segment-count ceiling above
-# which the auto backend falls back to the unfused body, one loop over the
-# trace's same-mode runs (jit trace/compile time grows with segment count;
-# heavily mode-interleaved programs like the wide convs are better served
-# by the unfused body).
+# which plain ``jax`` (``engine._variant``) runs the unfused body, one loop
+# over the trace's same-mode runs (jit trace/compile time grows with
+# segment count; heavily mode-interleaved programs like the wide convs are
+# better served by the unfused body).
 CHUNK = 8
 INLINE_MAX = 16
 JAX_FUSE_MAX_SEGMENTS = 64
@@ -275,7 +275,7 @@ def run_numpy_fused(cp: CompiledProgram, mem: np.ndarray,
 
 
 def jax_fuse_eligible(cp: CompiledProgram) -> bool:
-    """Whether the auto backend lowers ``cp`` through the fused jax path."""
+    """Whether plain ``jax`` lowers ``cp`` through the fused body."""
     return schedule_for(cp).n_segments <= JAX_FUSE_MAX_SEGMENTS
 
 

@@ -88,6 +88,13 @@ class CrossbarPlan:
             return cp
         return self._compiled
 
+    def _trace(self) -> CompiledProgram:
+        """The trace as compiled: a ``compile(fuse=False)`` trace is run as
+        it is, so the engine replays it unfused."""
+        if self._compiled is not None and self._compiled_src is self.program:
+            return self._compiled
+        return self.compile()
+
     def adopt_compiled(self, cp: CompiledProgram) -> CompiledProgram:
         """Install a deserialized trace as this plan's :meth:`compile` result.
 
@@ -172,7 +179,7 @@ class CrossbarPlan:
             xb.stats = {k: 0 for k in xb.stats}
             xb.run(self.program)
             return xb.mem, xb.cycles, dict(xb.stats)
-        res = execute(self.compile(), mem, backend=backend, faults=faults,
+        res = execute(self._trace(), mem, backend=backend, faults=faults,
                       rng=rng)
         return res.mem, res.cycles, res.stats
 
@@ -215,7 +222,6 @@ class CrossbarPlan:
         max_batch: Optional[int] = None,
         faults=None,
         rng=None,
-        tunings=None,
         mesh=None,
     ) -> EngineResult:
         """Run this plan's program over ``(B, rows, cols)`` crossbars at once.
@@ -239,6 +245,6 @@ class CrossbarPlan:
                 out[b] = xb.mem
             return EngineResult(mem=out, cycles=xb.cycles,
                                 stats=dict(xb.stats), backend="interp")
-        return execute(self.compile(), mems, backend=backend,
+        return execute(self._trace(), mems, backend=backend,
                        max_batch=max_batch, faults=faults, rng=rng,
-                       tunings=tunings, mesh=mesh)
+                       mesh=mesh)

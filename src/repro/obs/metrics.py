@@ -2,14 +2,13 @@
 
 Unlike the span tracer, metrics are **always on**: an update is one dict
 lookup plus an integer/float add, cheap enough for every ``engine.execute``
-call. The registry is the single source the serving layer, the autotuner
-and the engine publish into; :func:`snapshot` renders it as a stable
-(sorted, JSON-serializable) dict for ``BENCH_slo.json`` and ad-hoc dumps.
+call. The registry is the single source the serving layer and the engine
+publish into; :func:`snapshot` renders it as a stable (sorted,
+JSON-serializable) dict for ``BENCH_slo.json`` and ad-hoc dumps.
 
 Metric names are dotted paths with the owning layer first
-(``serve.request_latency_us``, ``engine.execute.wall_us.numpy-fused``,
-``autotune.resolve.measured``, …) — the catalog lives in
-``docs/ARCHITECTURE.md`` §Observability.
+(``serve.request_latency_us``, ``engine.execute.wall_us.numpy-fused``, …)
+— the catalog lives in ``docs/ARCHITECTURE.md`` §Observability.
 
 Histograms use fixed 1-2-5 geometric bucket bounds (µs-scaled by default),
 so quantile readout is a cumulative-count walk with linear interpolation

@@ -54,6 +54,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Tuple
 import numpy as np
 
 from ..core.compile import RunnerCache
+from ..core.engine import parse_backend
 from ..core.fused import prewarm_replay
 from ..core.tiling import (TiledBinaryMatvec, TiledConv2d, TiledMatvec,
                            majority_sign)
@@ -229,31 +230,19 @@ class PlanService:
                  fuse: bool = True, rows: int = 1024, cols: int = 1024,
                  parts: int = 32, bucket: bool = True, bucket_floor: int = 8,
                  max_batch: Optional[int] = None, seed: Optional[int] = 0,
-                 max_starve_steps: int = 4, tunings=None,
-                 autotune: Optional[bool] = None,
+                 max_starve_steps: int = 4,
                  async_compile: bool = False, compile_workers: int = 2,
                  compile_queue: int = 8, store=None,
                  devices: Optional[int] = None,
                  prewarm: Optional[bool] = None):
         self.max_plans = int(max_plans)
         self.fuse = bool(fuse)
+        # the engine's spellings plus the plan-level interpreter; an unknown
+        # one fails here, not at the first bucket. Fused or unfused is the
+        # engine's choice, made from each trace.
+        self._base = (backend if backend == "interp"
+                      else parse_backend(backend)[0])
         self.backend = backend
-        if not fuse and backend in ("numpy", "jax", "auto"):
-            # honor the unfused policy explicitly; auto would re-fuse
-            base = "numpy" if backend == "auto" else backend
-            self.backend = base + "-unfused"
-        # backend="auto": consult + refresh the autotuner's tunings table per
-        # (program, batch-bucket). ``tunings`` pins a specific TuningTable
-        # (tests, benches); None uses the process default ($MATPIM_TUNINGS).
-        # ``autotune`` (default: on iff backend == "auto") additionally
-        # micro-tunes COLD (program, bucket) pairs inline: the first batch of
-        # that shape times the real candidate variants (see
-        # core.autotune.autotune_execute) so every later batch in the stream
-        # runs the measured-fastest variant; tuning entries are keyed by
-        # trace content, so plan-cache eviction never orphans them.
-        self.tunings = tunings
-        self._auto = self.backend == "auto"
-        self.autotune = self._auto if autotune is None else bool(autotune)
         self.geometry = (int(rows), int(cols), int(parts))
         self.bucket = bool(bucket)
         self.bucket_floor = int(bucket_floor)
@@ -384,10 +373,9 @@ class PlanService:
         jax compilation cache), never service state.
         """
         t0 = time.perf_counter()
-        backend = self.backend
-        if backend in ("numpy", "auto", "numpy-fused", "numpy-unfused"):
+        if self._base == "numpy":
             prewarm_replay(cp)
-        if backend in ("jax", "jax-fused", "jax-unfused", "auto"):
+        elif self._base == "jax":
             from ..core.engine import execute, have_jax, word_widths
             if have_jax():
                 # one dummy per width a word can ship (a partial word pads
@@ -396,8 +384,8 @@ class PlanService:
                 # few ms on top
                 for width in word_widths(self.max_batch):
                     dummy = np.zeros((width, cp.rows, cp.cols), np.uint8)
-                    execute(cp, dummy, backend="jax" if backend == "auto"
-                            else backend, max_batch=self.max_batch)
+                    execute(cp, dummy, backend=self.backend,
+                            max_batch=self.max_batch)
         return time.perf_counter() - t0
 
     def _prewarm_async(self, key: tuple, w) -> bool:
@@ -746,47 +734,6 @@ class PlanService:
             out.setdefault(self._exec_key(p), []).append(p)
         return out
 
-    def _execute_bucket(self, plan, mems: np.ndarray, faults, rng):
-        """One engine call for a coalesced bucket; the autotuner's
-        observation point when the service runs ``backend="auto"``.
-
-        Cold ``(program key, batch bucket)`` pairs (no tunings entry yet) are
-        micro-tuned inline on the real batch — the winning candidate's result
-        is the bucket's result, so the probe replays are the only overhead,
-        paid once per pair and persisted. Warm pairs execute the measured
-        variant and fold their wall time back into the (in-memory) table, so
-        a drifting machine re-converges without an explicit re-tune.
-        """
-        if self._auto and faults is None:
-            from ..core import autotune as at
-            cp = plan.compile(fuse=self.fuse)
-            table = (self.tunings if self.tunings is not None
-                     else at.get_default_table())
-            key = at.program_key(cp)
-            bucket = at.batch_bucket(mems.shape[0])
-            if self.autotune and table.lookup(key, bucket) is None:
-                _metrics.counter("serve.inline_tunes").inc()
-                res, _ = at.autotune_execute(cp, mems, table, cheap=True)
-                return res
-            t0 = time.perf_counter()
-            res = plan.execute_batch(mems, backend=self.backend,
-                                     max_batch=self.max_batch, tunings=table)
-            us = (time.perf_counter() - t0) * 1e6
-            resolved = res.backend
-            if resolved.startswith("auto:"):
-                # label grammar: auto:<backend>[@<max_batch>][+mesh<D>] —
-                # sharded walls train the entry for *that* topology only
-                resolved, _, meshpart = \
-                    resolved[len("auto:"):].partition("+mesh")
-                resolved, _, mb = resolved.partition("@")
-                table.observe(key, bucket, resolved, us,
-                              max_batch=int(mb) if mb else None,
-                              topo=int(meshpart) if meshpart else 1)
-            return res
-        return plan.execute_batch(mems, backend=self.backend,
-                                  max_batch=self.max_batch, faults=faults,
-                                  rng=rng, tunings=self.tunings)
-
     def _device_ctx(self, slot: int):
         """Pin a bucket's engine work to local jax device ``slot``.
 
@@ -797,8 +744,7 @@ class PlanService:
         one device queue.
         """
         import contextlib
-        if self.devices <= 1 or not (
-                self.backend == "auto" or self.backend.startswith("jax")):
+        if self.devices <= 1 or self._base != "jax":
             return contextlib.nullcontext()
         from ..core.engine import have_jax
         if not have_jax():
@@ -843,7 +789,9 @@ class PlanService:
                     w._served_once = True
                 t0 = time.perf_counter()
                 with self._device_ctx(slot):
-                    res = self._execute_bucket(plan, mems, faults, rng)
+                    res = plan.execute_batch(
+                        mems, backend=self.backend, max_batch=self.max_batch,
+                        faults=faults, rng=rng)
                 wall = time.perf_counter() - t0
                 _metrics.counter(f"serve.device.{slot}.batches").inc()
                 _metrics.histogram(f"serve.device.{slot}.busy_us") \

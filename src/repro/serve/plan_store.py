@@ -22,8 +22,8 @@ Storage contract
   no code execution from disk) holding the flat arrays from
   ``core.compile.compiled_state`` plus a ``__meta__`` uint8 array carrying
   the JSON meta: store schema tag, the plan-key repr, the content-derived
-  ``core.autotune.program_key`` of the trace (an integrity cross-check
-  recomputed after deserialization), and the compiled-state meta.
+  :func:`program_key` of the trace (an integrity cross-check recomputed
+  after deserialization), and the compiled-state meta.
 * Writes are atomic: ``tempfile.mkstemp`` in the store directory, then
   ``os.replace`` — a reader never observes a torn entry, and a writer
   killed mid-write leaves only an ignored ``.tmp-*`` file (SIGKILL-tested).
@@ -45,7 +45,6 @@ from typing import List, Optional
 
 import numpy as np
 
-from ..core import autotune as _autotune
 from ..core.compile import (CompiledProgram, compiled_from_state,
                             compiled_state)
 from ..obs import metrics as _metrics
@@ -56,8 +55,23 @@ SCHEMA = 1
 # env var naming the default on-disk plan store; unset -> no persistence
 STORE_ENV = "MATPIM_PLAN_STORE"
 
-__all__ = ["PlanStore", "STORE_ENV", "get_default_store",
+__all__ = ["PlanStore", "STORE_ENV", "get_default_store", "program_key",
            "reset_default_store", "store_key"]
+
+
+def program_key(cp) -> str:
+    """Content-derived key for a compiled trace.
+
+    Built only from trace invariants (geometry, cycle count, padded widths,
+    op-category stats, fused segment count), so recompiling the same plan —
+    after plan-cache eviction, or in another process — maps back to the
+    same key. A store entry records it at ``put`` and checks it after
+    deserialization.
+    """
+    seg = cp.schedule.n_segments if cp.schedule is not None else -1
+    stats = ";".join(f"{k}={v}" for k, v in sorted(cp.stats.items()))
+    return (f"r{cp.rows}c{cp.cols}t{cp.n_cycles}w{cp.W}i{cp.I}"
+            f"s{seg}[{stats}]")
 
 
 def store_key(plan_key: object) -> str:
@@ -124,7 +138,7 @@ class PlanStore:
                                          "collision or renamed entry)")
                     arrays = {k: z[k] for k in z.files if k != "__meta__"}
                 cp = compiled_from_state(meta["compiled"], arrays)
-                if _autotune.program_key(cp) != meta.get("program_key"):
+                if program_key(cp) != meta.get("program_key"):
                     raise ValueError("program_key integrity check failed")
         except Exception as e:
             # truncated zip, stale schema, bad shapes, key mismatch: all
@@ -149,7 +163,7 @@ class PlanStore:
         meta = {
             "store_schema": SCHEMA,
             "plan_key": repr(plan_key),
-            "program_key": _autotune.program_key(cp),
+            "program_key": program_key(cp),
             "compiled": cmeta,
         }
         blob = np.frombuffer(json.dumps(meta, sort_keys=True).encode(),
@@ -186,9 +200,9 @@ _DEFAULT_PATH: Optional[str] = None
 def get_default_store() -> Optional[PlanStore]:
     """The ``$MATPIM_PLAN_STORE`` store, or ``None`` when the env is unset.
 
-    Re-checks the environment on every call (mirroring
-    ``autotune.get_default_table``) so tests and long-lived processes can
-    repoint it; the store object is reused while the path is unchanged.
+    Re-checks the environment on every call so tests and long-lived
+    processes can repoint it; the store object is reused while the path is
+    unchanged.
     """
     global _DEFAULT, _DEFAULT_PATH
     path = os.environ.get(STORE_ENV)

@@ -25,6 +25,7 @@ from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
 
 from repro.core import (Crossbar, compile_program, execute, fuse_program,
                         have_jax, parse_backend)
+from repro.core.fused import JAX_FUSE_MAX_SEGMENTS
 from repro.core.compile import MODE_INIT
 from repro.core.isa import GATES, ColOp, InitOp, RowOp
 from repro.device.faults import FaultModel, FaultRealization
@@ -243,25 +244,26 @@ def test_fusion_cycle_accounting_invariant():
 def test_backend_name_parsing_and_contracts():
     from repro.core import available_backends
 
-    assert parse_backend("numpy") == ("numpy", "auto")
+    assert parse_backend("numpy") == ("numpy", None)
     assert parse_backend("numpy-unfused") == ("numpy", "unfused")
     assert parse_backend("jax-fused") == ("jax", "fused")
-    assert parse_backend("auto") == ("auto", "auto")
-    assert parse_backend("pallas") == ("pallas", "auto")
+    assert parse_backend("pallas") == ("pallas", None)
     with pytest.raises(ValueError):
         parse_backend("interp")        # plan-level only
     with pytest.raises(ValueError):
         parse_backend("torch")
-    for bad in ("auto-fused", "pallas-unfused"):
+    for bad in ("auto", "auto-fused", "pallas-unfused"):
         with pytest.raises(ValueError):
-            parse_backend(bad)         # meta-backends take no variant suffix
+            parse_backend(bad)         # no meta-backend; pallas takes no suffix
     with pytest.raises(ValueError) as ei:
         parse_backend("torch")
-    # the error enumerates the real set (the old message named only 2 of 8)
-    for be in ("auto", "numpy-unfused", "jax-fused", "pallas"):
+    # the error enumerates the real set
+    for be in ("numpy-unfused", "jax-fused", "pallas"):
         assert f"'{be}'" in str(ei.value)
+    assert "'auto'" not in str(ei.value)
     bs = available_backends()
-    assert {"auto", "numpy", "numpy-fused", "numpy-unfused"} <= set(bs)
+    assert {"numpy", "numpy-fused", "numpy-unfused"} <= set(bs)
+    assert "auto" not in bs
     assert ("jax" in bs) == have_jax() and ("pallas" in bs) == have_jax()
 
     prog = [[ColOp("NOT", (0,), 1, None)]]
@@ -285,8 +287,125 @@ def test_unfused_compile_still_executes():
             [ColOp("NOT", (0,), 1, None)]]
     cp = compile_program(prog, 8, 8, 1, 1, fuse=False)
     mem = np.zeros((8, 8), np.uint8)
-    a = execute(cp, mem, backend="numpy").mem          # auto -> unfused
+    a = execute(cp, mem, backend="numpy").mem          # rule -> unfused
     assert cp.schedule is None
     b = execute(cp, mem, backend="numpy-fused").mem    # attaches on demand
     assert cp.schedule is not None
     np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# The one rule that picks the fused or unfused body (engine._variant)
+# ---------------------------------------------------------------------------
+
+needs_jax = pytest.mark.skipif(not have_jax(), reason="needs jax")
+
+
+def _rule_trace(kind):
+    """A fuse-eligible trace, one past JAX_FUSE_MAX_SEGMENTS segments (col
+    and row cycles alternate, so every cycle is its own segment), or a
+    random program compiled with ``fuse=False``."""
+    if kind == "long":
+        prog = [[ColOp("NOT", (0,), 1, None)] if t % 2 == 0
+                else [RowOp("NOT", (0,), 1, None)]
+                for t in range(JAX_FUSE_MAX_SEGMENTS + 2)]
+        return compile_program(prog, 8, 8, 1, 1)
+    prog, rows, cols, parts = random_program(3)
+    return compile_program(prog, rows, cols, parts, parts,
+                           fuse=kind != "unfused")
+
+
+def _rule_faults(kind, cp, B):
+    if kind == "model":
+        return FaultModel()                       # ideal, on the FaultModel path
+    if kind == "real":
+        fm = FaultModel(p_sa0=0.03, p_sa1=0.03, p_switch=0.08, p_init=0.08)
+        return FaultRealization.sample(fm, B, cp.rows, cp.cols, cp.n_cycles,
+                                       cp.W, cp.I, rng=5)
+    return None
+
+
+# (trace, faults, backend) -> the body the rule must pick
+RULE = [
+    ("eligible", None, "numpy", "fused"),
+    ("eligible", None, "jax", "fused"),
+    ("eligible", "model", "numpy", "fused"),
+    ("eligible", "model", "jax", "unfused"),
+    ("eligible", "real", "numpy", "fused"),
+    ("eligible", "real", "jax", "fused"),
+    ("long", None, "numpy", "fused"),
+    ("long", None, "jax", "unfused"),
+    ("long", "model", "numpy", "fused"),
+    ("long", "model", "jax", "unfused"),
+    ("long", "real", "numpy", "fused"),
+    ("long", "real", "jax", "fused"),
+    ("unfused", None, "numpy", "unfused"),
+    ("unfused", None, "jax", "unfused"),
+    ("unfused", "model", "numpy", "unfused"),
+    ("unfused", "model", "jax", "unfused"),
+    ("unfused", "real", "numpy", "fused"),
+    ("unfused", "real", "jax", "fused"),
+    # an explicit variant is obeyed whatever the trace says
+    ("eligible", None, "numpy-unfused", "unfused"),
+    ("eligible", None, "jax-unfused", "unfused"),
+    ("unfused", None, "numpy-fused", "fused"),
+    ("unfused", None, "jax-fused", "fused"),
+]
+
+
+def _runner_key(base, variant, faults):
+    """The ``cp._caches`` entry the chosen runner is memoised under."""
+    if base == "numpy":
+        return "numpy_fused_plan" if variant == "fused" else "numpy_plan"
+    if variant == "fused":
+        return (("jax_fused_real",) if isinstance(faults, FaultRealization)
+                else ("jax_fused",))
+    return ("jax_runner_faulty" if isinstance(faults, FaultModel)
+            else "jax_runner")
+
+
+@pytest.mark.parametrize(
+    "trace,fault_kind,backend,want",
+    [pytest.param(*case, id="-".join(str(c) for c in case),
+                  marks=[needs_jax] if case[2].startswith("jax") else [])
+     for case in RULE])
+def test_executor_rule(trace, fault_kind, backend, want):
+    """Which body runs is read off the trace, the faults and an explicit
+    suffix; whichever it is, memory, cycles and stats equal a
+    ``numpy-unfused`` run under the same faults."""
+    cp = _rule_trace(trace)
+    if trace == "long":
+        assert cp.schedule.n_segments > JAX_FUSE_MAX_SEGMENTS
+    rng = np.random.default_rng(11)
+    B = 3
+    mems = (rng.random((B, cp.rows, cp.cols)) < 0.5).astype(np.uint8)
+    faults = _rule_faults(fault_kind, cp, B)
+    res = execute(cp, mems, backend=backend, faults=faults, rng=0)
+    base = parse_backend(backend)[0]
+    other = "unfused" if want == "fused" else "fused"
+    assert _runner_key(base, want, faults) in cp._caches
+    assert _runner_key(base, other, faults) not in cp._caches
+    ref = execute(_rule_trace(trace), mems, backend="numpy-unfused",
+                  faults=faults, rng=0)
+    np.testing.assert_array_equal(res.mem, ref.mem)
+    assert res.cycles == ref.cycles and res.stats == ref.stats
+
+
+@pytest.mark.parametrize("entry", ["parse_backend", "execute", "PlanService"])
+def test_auto_backend_refused(entry):
+    """``"auto"`` is not a backend: every entry point refuses it with the
+    error that lists the accepted spellings."""
+    from repro.serve.matpim import PlanService
+
+    calls = {
+        "parse_backend": lambda: parse_backend("auto"),
+        "execute": lambda: execute(
+            compile_program([[ColOp("NOT", (0,), 1, None)]], 8, 8, 1, 1),
+            np.zeros((8, 8), np.uint8), backend="auto"),
+        "PlanService": lambda: PlanService(backend="auto", store=False),
+    }
+    with pytest.raises(ValueError, match="unknown engine backend 'auto'") \
+            as ei:
+        calls[entry]()
+    for be in ("numpy", "numpy-unfused", "jax", "jax-fused", "pallas"):
+        assert f"'{be}'" in str(ei.value)

@@ -191,27 +191,6 @@ def test_fixed_fault_realization_masks_identical_under_mesh():
     np.testing.assert_array_equal(yf0, yf1)
 
 
-@multidev
-def test_auto_backend_resolves_through_mesh_topology():
-    """backend="auto" under a mesh keys its tuning lookup by topology: a
-    1-device measured entry must not decide the 8-device execute."""
-    from repro.core import autotune as at
-    from repro.distributed.mesh_exec import tile_mesh
-
-    t, (A, x) = _wrappers()["binary_matvec"]
-    cp = t.plan.compile()
-    table = at.TuningTable()
-    key = at.program_key(cp)
-    bucket = at.batch_bucket(t.n_tiles)
-    table.record(key, bucket, "numpy-unfused", 123.0)      # topo=1, measured
-    be, mb, src = at.resolve_auto(cp, t.n_tiles, table=table, topo=8)
-    assert src == "heuristic" and be.startswith("jax")
-    y1, r1 = t.run(A, x, backend="auto", mesh=tile_mesh(8))
-    assert "+mesh8" in r1.backend, r1.backend
-    y0, _ = t.run(A, x, backend="jax")
-    np.testing.assert_array_equal(y0, y1)
-
-
 # ---------------------------------------------------------------------------
 # Serving layer: multi-device bucket dispatch vs the serial loop
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """Telemetry subsystem (repro.obs): span tracer, metrics registry, and the
-instrumentation threaded through compile/engine/autotune/serve.
+instrumentation threaded through compile/engine/serve.
 
 Covers the ISSUE-7 acceptance contract: the disabled tracing path adds <2%
 to ``engine.execute``, and a Chrome-trace JSON recorded from a mixed
@@ -194,7 +194,7 @@ def test_histogram_overflow_bucket_clamps_to_max():
 
 
 # ---------------------------------------------------------------------------
-# instrumentation: compile / engine / autotune / serve
+# instrumentation: compile / engine / serve
 # ---------------------------------------------------------------------------
 
 
@@ -307,34 +307,10 @@ def test_engine_fault_run_sets_fault_gauges():
     assert metrics.gauge("engine.fault.p_sa0").value == 0.0
 
 
-def test_compile_and_autotune_resolve_metrics():
-    from repro.core.autotune import TuningTable, program_key, resolve_auto
+def test_compile_metrics():
     cp, mem = _small_plan()               # compiles once inside plan.compile
     assert metrics.counter("compile.programs").value >= 1
     assert metrics.counter("compile.seconds").value > 0
-    table = TuningTable()
-    be, mb, src = resolve_auto(cp, 2, table=table)
-    assert src == "heuristic"
-    assert metrics.counter("autotune.resolve.heuristic").value == 1
-    from repro.core.autotune import batch_bucket
-    table.record(program_key(cp), batch_bucket(2), be, 100.0)
-    _, _, src = resolve_auto(cp, 2, table=table)
-    assert src == "measured"
-    assert metrics.counter("autotune.resolve.measured").value == 1
-
-
-def test_autotune_execute_probe_counters():
-    from repro.core.autotune import TuningTable, autotune_execute, candidates
-    cp, mem = _small_plan()
-    table = TuningTable()
-    res, entry = autotune_execute(cp, mem, table, reps=1, cheap=True,
-                                  save=False)
-    n_cand = len(candidates(cp, mem.shape[0], cheap=True))
-    assert metrics.counter("autotune.probes").value == n_cand
-    win = metrics.counter(
-        f"autotune.wins.{entry.backend}"
-        + (f"@{entry.max_batch}" if entry.max_batch else ""))
-    assert win.value == 1
 
 
 def test_serve_cache_and_latency_metrics():

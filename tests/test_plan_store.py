@@ -23,7 +23,7 @@ from repro.core.engine import execute
 from repro.obs import metrics
 from repro.serve import plan_store
 from repro.serve.matpim import PlanService
-from repro.serve.plan_store import PlanStore, store_key
+from repro.serve.plan_store import PlanStore, program_key, store_key
 
 GEOM = dict(rows=64, cols=256, parts=8)
 SRC = str(Path(__file__).resolve().parent.parent / "src")
@@ -142,6 +142,18 @@ def test_store_key_is_process_stable(tmp_path):
         capture_output=True, text=True, check=True,
         env={**os.environ, "PYTHONHASHSEED": "12345"})
     assert out.stdout.strip() == store_key(key)
+
+
+def test_program_key_stable_across_recompiles():
+    plan = BinaryMatvecPlan(4, 16, **GEOM)
+    k1 = program_key(plan.compile())
+    plan._compiled = None                    # simulate cache eviction
+    k2 = program_key(plan.compile())
+    fresh = BinaryMatvecPlan(plan.m, plan.n, **GEOM)
+    k3 = program_key(fresh.compile())
+    assert k1 == k2 == k3
+    other = BinaryMatvecPlan(plan.m, plan.n * 2, **GEOM)
+    assert program_key(other.compile()) != k1
 
 
 # ---------------------------------------------------------------------------

@@ -434,11 +434,16 @@ def test_minority_bucket_not_starved():
 
 def test_unfused_service_policy():
     svc = PlanService(fuse=False, **GEOM)
-    assert svc.backend == "numpy-unfused"
+    assert svc.backend == "numpy"        # passed through; the trace decides
     A = np.ones((3, 9), dtype=int)
     t = svc.submit_binary_matvec(A, np.ones(9, dtype=int))
     svc.flush()
     assert np.array_equal(t.result, [1, 1, 1])
+    (w,) = svc._plans.values()
+    cp = w.plan._compiled
+    assert cp.schedule is None           # compiled unfused and run unfused
+    assert "numpy_plan" in cp._caches
+    assert "numpy_fused_plan" not in cp._caches
 
 
 def test_pipeline_stages_share_default_service():

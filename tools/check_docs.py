@@ -26,7 +26,6 @@ ROOT = Path(__file__).resolve().parent.parent
 # modules whose docstring examples are contractual (the core/device/apps
 # public surface; extend as examples are added)
 DOCTEST_MODULES = [
-    "repro.core.autotune",
     "repro.core.compile",
     "repro.core.crossbar",
     "repro.core.engine",
