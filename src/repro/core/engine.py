@@ -13,7 +13,9 @@ and an unfused (per-cycle) variant:
   (``fused.build_jax_fused``). The unfused variant loops over the
   trace's maximal same-mode runs, each replayed cycle by cycle in a loop
   specialised to its mode, with no per-cycle ``lax.switch`` either — the
-  fallback for traces of many segments. ``FaultModel`` injection keeps a
+  fallback for traces of many segments. On a TPU each column and row run
+  is instead one Pallas kernel call that replays only the live gates
+  (``core/replay_kernel.py``). ``FaultModel`` injection keeps a
   per-cycle ``lax.scan`` + ``lax.switch``. Gated: raises cleanly when jax
   is absent.
 
@@ -456,6 +458,31 @@ def mode_runs(cp: CompiledProgram) -> np.ndarray:
     return np.stack([start, end, mode[start]], axis=1).astype(np.int32)
 
 
+def init_replay(cp: CompiledProgram):
+    """``replay(t, buf) -> buf``: init cycle ``t`` of ``cp`` on one packed
+    ``(C+1, R+1)`` uint32 word, each rectangle a whole-word select. Both
+    unfused bodies replay init cycles with it."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    dt = jnp.dtype(np.uint32)
+    ones = dt.type(0xFFFFFFFF)
+    row_masks = jnp.asarray(cp.row_masks)
+    col_masks = jnp.asarray(cp.col_masks)
+    init = tuple(jnp.asarray(a) for a in (cp.init_r, cp.init_c, cp.init_v))
+
+    def replay(t, buf):
+        r, c, v = (lax.dynamic_index_in_dim(a, t, keepdims=False)
+                   for a in init)
+        for i in range(cp.I):
+            region = col_masks[c[i]][:, None] & row_masks[r[i]][None, :]
+            buf = jnp.where(region, jnp.where(v[i] > 0, ones, dt.type(0)),
+                            buf)
+        return buf
+
+    return replay
+
+
 def _build_jax_body(cp: CompiledProgram):
     """Un-jitted unfused body ``body(buf) -> buf`` over one packed
     ``(C+1, R+1)`` uint32 word of the canonical buffer (see
@@ -472,8 +499,6 @@ def _build_jax_body(cp: CompiledProgram):
     from jax import lax
 
     R1, C1, W = cp.rows + 1, cp.cols + 1, cp.W
-    dt = jnp.dtype(np.uint32)
-    ones = dt.type(0xFFFFFFFF)
     row_masks = jnp.asarray(cp.row_masks)
     col_masks = jnp.asarray(cp.col_masks)
     runs = mode_runs(cp)
@@ -483,9 +508,6 @@ def _build_jax_body(cp: CompiledProgram):
         # one (W*5,) row a cycle: a TPU pads a trailing axis of 5 to 128
         "ins": jnp.asarray(cp.ins.reshape(cp.n_cycles, W * MAX_FANIN)),
         "sel": jnp.asarray(cp.sel),
-        "init_r": jnp.asarray(cp.init_r),
-        "init_c": jnp.asarray(cp.init_c),
-        "init_v": jnp.asarray(cp.init_v),
     }
     iota_w = jnp.arange(W)
 
@@ -511,14 +533,6 @@ def _build_jax_body(cp: CompiledProgram):
         new = jnp.where(mask, out, old)
         return buf.at[:, x["dst"]].set(new.T)
 
-    def init_step(buf, x):
-        for i in range(cp.I):
-            region = col_masks[x["init_c"][i]][:, None] \
-                & row_masks[x["init_r"][i]][None, :]
-            word = jnp.where(x["init_v"][i] > 0, ones, dt.type(0))
-            buf = jnp.where(region, word, buf)
-        return buf
-
     def at_cycle(step):
         def replay(t, buf):
             return step(buf, {k: lax.dynamic_index_in_dim(a, t, keepdims=False)
@@ -526,7 +540,7 @@ def _build_jax_body(cp: CompiledProgram):
         return replay
 
     loops = ((MODE_COL, at_cycle(col_step)), (MODE_ROW, at_cycle(row_step)),
-             (MODE_INIT, at_cycle(init_step)))
+             (MODE_INIT, init_replay(cp)))
     run_table = jnp.asarray(runs)
 
     def run(r, buf):
@@ -663,7 +677,8 @@ def mode_cycles(cp: CompiledProgram) -> Tuple[int, int, int]:
 def replay_words(mem: np.ndarray, run, call: Optional[int] = None,
                  word_args=None,
                  modes: Optional[Tuple[int, int, int]] = None,
-                 runs: Optional[int] = None) -> np.ndarray:
+                 runs: Optional[int] = None,
+                 live_gates: Optional[int] = None) -> np.ndarray:
     """Replay ``mem`` through the word program ``run``
     (:func:`device_word_program`) one packed word of 32 crossbars at a
     time: the host path every multi-word jax runner shares.
@@ -690,7 +705,10 @@ def replay_words(mem: np.ndarray, run, call: Optional[int] = None,
     ``engine.replay.row_cycles`` and ``engine.replay.init_cycles`` grow by
     its cycles of each mode for every word replayed; given the number of
     same-mode ``runs`` the unfused body loops over (:func:`mode_runs`),
-    ``engine.replay.mode_runs`` grows by it for every word replayed.
+    ``engine.replay.mode_runs`` grows by it for every word replayed; given
+    the ``live_gates`` the TPU's replay kernel replays
+    (:func:`repro.core.replay_kernel.replay_table`),
+    ``engine.replay.live_gates`` grows by them for every word replayed.
     """
     from concurrent.futures import ThreadPoolExecutor
 
@@ -741,16 +759,32 @@ def replay_words(mem: np.ndarray, run, call: Optional[int] = None,
             _metrics.counter(f"engine.replay.{name}_cycles").inc(n * words)
     if runs is not None:
         _metrics.counter("engine.replay.mode_runs").inc(runs * words)
+    if live_gates is not None:
+        _metrics.counter("engine.replay.live_gates").inc(live_gates * words)
     return out
 
 
 def _build_jax_runner(cp: CompiledProgram):
-    run = device_word_program(jax_unfused_body(cp), cp.rows, cp.cols)
+    """The unfused jax runner. On a TPU its body replays each column and
+    row run in one Pallas kernel call
+    (:func:`repro.core.replay_kernel.pallas_unfused_body`); elsewhere,
+    where Pallas would only interpret, it is the XLA body
+    (:func:`jax_unfused_body`)."""
+    from ..kernels.ops import interpret_mode
+    live = None
+    if interpret_mode():
+        body = jax_unfused_body(cp)
+    else:
+        from . import replay_kernel
+        body = replay_kernel.pallas_unfused_body(cp)
+        live = replay_kernel.replay_table(cp).live
+    run = device_word_program(body, cp.rows, cp.cols)
     modes = mode_cycles(cp)
     runs = len(mode_runs(cp))
 
     def runner(mem_np: np.ndarray, call: Optional[int] = None) -> np.ndarray:
-        return replay_words(mem_np, run, call, modes=modes, runs=runs)
+        return replay_words(mem_np, run, call, modes=modes, runs=runs,
+                            live_gates=live)
 
     return runner
 

@@ -14,6 +14,8 @@ Compiled, at the paper's 1024x1024 / 32-partition geometry:
   3, 32)`` (Table II) with no copy of the whole word in any per-cycle loop;
 * the per-word program around each of the three (32 crossbars in, pack,
   replay, unpack, 32 out), with its scratch bytes bounded;
+* the word program of the matvec and the conv as a TPU runs it, with the
+  replay kernel in place of the per-cycle loops (``core/replay_kernel.py``);
 * the three Pallas kernels at the operand shapes the pallas backend passes
   for the paper's binary matvec, matvec and conv plans;
 * the ``shard_map`` tile runner on a mesh of the four described devices.
@@ -179,6 +181,44 @@ def test_device_word_program_at_paper_geometry(one_chip, kind):
     x = jax.ShapeDtypeStruct((WORD_BITS, cp.rows, cp.cols // 4), jnp.uint32,
                              sharding=one_chip)
     compiled = device_word_program(body, cp.rows, cp.cols).lower(x).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == np.prod(block)
+    assert mem.temp_size_in_bytes < 4 * np.prod(block)
+
+
+@pytest.mark.parametrize("kind", ["matvec", "conv"])
+def test_replay_kernel_word_program_at_paper_geometry(one_chip, kind):
+    """The word program a TPU runs for the Table I matvec and the Table II
+    conv: 32 uint8 crossbars in, packed, replayed with one replay-kernel
+    call per column or row run, unpacked. Every column and row cycle runs
+    inside the two kernels: the only while loops are the run loop, which
+    calls both, and the init-cycle loop inside it, which calls none. Its
+    scratch stays as bounded as the XLA body's word program."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.engine import WORD_BITS, device_word_program
+    from repro.core.replay_kernel import pallas_unfused_body
+    from repro.launch.hlo_analysis import parse_computations
+
+    cp = _unfused_program(kind)
+    block = (WORD_BITS, cp.rows, cp.cols)
+    x = jax.ShapeDtypeStruct((WORD_BITS, cp.rows, cp.cols // 4), jnp.uint32,
+                             sharding=one_chip)
+    compiled = device_word_program(pallas_unfused_body(cp), cp.rows,
+                                   cp.cols).lower(x).compile()
+    text = compiled.as_text()
+    comps = parse_computations(text)
+    loops = []
+    for line in text.splitlines():
+        if " while(" in line:
+            inner = _reachable(comps,
+                               re.search(r"body=([%\w.\-]+)", line).group(1))
+            lines = [ln for c in inner for ln in comps[c]]
+            loops.append((sum("tpu_custom_call" in ln for ln in lines),
+                          sum(" while(" in ln for ln in lines)))
+    assert sorted(loops) == [(0, 0), (2, 1)], loops
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes == np.prod(block)
     assert mem.temp_size_in_bytes < 4 * np.prod(block)

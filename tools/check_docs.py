@@ -29,6 +29,7 @@ DOCTEST_MODULES = [
     "repro.core.compile",
     "repro.core.crossbar",
     "repro.core.engine",
+    "repro.core.replay_kernel",
     "repro.core.latency",
     "repro.core.plan",
     "repro.core.tiling",
