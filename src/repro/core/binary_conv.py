@@ -38,6 +38,9 @@ from .plan import CrossbarPlan
 class BinaryConvPlan(CrossbarPlan):
     """±1-kernel conv: out = [XNOR-tap popcount ≥ ⌈k²/2⌉], in ±1.
 
+    The program is specialised to the kernel, so the plan compiles once
+    :meth:`ensure_program` has given it one (``run`` calls it).
+
     >>> plan = BinaryConvPlan(4, 8, 2, rows=64, cols=256, parts=8)
     >>> A = np.where(np.arange(32).reshape(4, 8) % 2 == 0, 1, -1)
     >>> out, cycles = plan.run(A, np.ones((2, 2)))
@@ -205,9 +208,9 @@ class BinaryConvPlan(CrossbarPlan):
     # -- driver ---------------------------------------------------------------
 
     def ensure_program(self, K: np.ndarray) -> Program:
+        """(Re)build the program if missing or specialised to another K."""
         if self.program is None or not np.array_equal(K, self.K):
-            self.program = self.build(K)
-            self.K = K.copy()
+            self._build_for_kernel("binary_conv", K)
         return self.program
 
     def load_into(self, mem: np.ndarray, A: np.ndarray, K: np.ndarray) -> None:
@@ -238,7 +241,7 @@ class BinaryConvPlan(CrossbarPlan):
     @property
     def cycles(self) -> int:
         if self.program is None:
-            self.program = self.build(np.ones((self.k, self.k)))
+            self.ensure_program(np.ones((self.k, self.k), np.int64))
         return len(self.program)
 
 

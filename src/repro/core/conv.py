@@ -225,8 +225,7 @@ class ConvPlan(CrossbarPlan):
         """(Re)build the program if missing or specialized to a different K."""
         k_dependent = self.specialize or self.stream_kernel
         if self.program is None or (k_dependent and not np.array_equal(K, self.K)):
-            self.program = self.build(K)
-            self.K = K.copy()
+            self._build_for_kernel("conv", K)
         return self.program
 
     def load_into(self, mem: np.ndarray, A: np.ndarray, K: np.ndarray) -> None:
@@ -273,7 +272,7 @@ class ConvPlan(CrossbarPlan):
             if self.specialize or self.stream_kernel:
                 # K-dependent program: cycle count is K-independent in
                 # structure for streaming; use a dummy kernel
-                self.program = self.build(np.ones((self.k, self.k), dtype=np.int64))
+                self.ensure_program(np.ones((self.k, self.k), dtype=np.int64))
             else:
                 self.program = self.build()
         return len(self.program)

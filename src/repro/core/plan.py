@@ -33,6 +33,8 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from ..obs import metrics as _metrics
+from ..obs.trace import span as _span
 from .compile import CompiledProgram, compile_program
 from .crossbar import Crossbar
 from .engine import EngineResult, execute
@@ -68,7 +70,11 @@ class CrossbarPlan:
     def compile(self, validate: bool = True,
                 fuse: bool = True) -> CompiledProgram:
         prog = self.program
-        assert prog is not None, "plan has no program built yet"
+        if prog is None:
+            raise RuntimeError(
+                f"{type(self).__name__} has no program built yet: its "
+                "program is specialised to the kernel, so call "
+                "ensure_program(K) before compile()")
         if self._compiled is None or self._compiled_src is not prog:
             self._compiled = compile_program(
                 prog, self.rows, self.cols, self.parts, self.parts,
@@ -87,6 +93,17 @@ class CrossbarPlan:
             cp.pallas_spec = self.pallas_spec()
             return cp
         return self._compiled
+
+    def _build_for_kernel(self, op: str, K: np.ndarray) -> list:
+        """Build and install the program for kernel ``K`` (the conv plans'
+        ``ensure_program``), under a ``plan.build`` span, stepping the
+        ``plan.kernel_programs`` counter once."""
+        with _span("plan.build", op=op) as sp:
+            self.program = self.build(K)
+            sp.set(cycles=len(self.program))
+        _metrics.counter("plan.kernel_programs").inc()
+        self.K = np.array(K, copy=True)
+        return self.program
 
     def _trace(self) -> CompiledProgram:
         """The trace as compiled: a ``compile(fuse=False)`` trace is run as

@@ -127,6 +127,10 @@ def test_conv_kernel_specialization_faster():
     assert fast < base
 
 
+TRACE_FIELDS = ("mode", "nops", "gate", "dst", "ins", "sel", "init_r",
+                "init_c", "init_v", "row_masks", "col_masks")
+
+
 def test_conv_compile_builds_a_kernel_independent_program():
     """A fresh plan compiles without ``.cycles`` or ``run`` first, to the
     trace those would have built; a K-dependent plan still needs its K."""
@@ -135,11 +139,10 @@ def test_conv_compile_builds_a_kernel_independent_program():
     assert plan.cycles == fresh.n_cycles
     after = plan.compile()
     assert fresh.stats == after.stats
-    for field in ("mode", "nops", "gate", "dst", "ins", "sel", "init_r",
-                  "init_c", "init_v", "row_masks", "col_masks"):
+    for field in TRACE_FIELDS:
         np.testing.assert_array_equal(getattr(fresh, field),
                                       getattr(after, field))
-    with pytest.raises(AssertionError, match="no program built"):
+    with pytest.raises(RuntimeError, match="ensure_program"):
         ConvPlan(64, 6, 3, 8, specialize_kernel=True).compile()
 
 
@@ -156,6 +159,33 @@ def test_binary_conv(m, n, k):
     plan = BinaryConvPlan(m, n, k)
     out, cycles = plan.run(A, K)
     assert np.array_equal(out, ref_binary_conv(A, K))
+
+
+def test_binary_conv_compiles_with_the_kernel_it_is_given():
+    """A fresh plan given K by ``ensure_program`` compiles to the trace of
+    a plan that built another kernel's program first (``.cycles``, all
+    ones) and was then given K, and runs to the plain reference."""
+    K = np.array([[1, 1, -1], [-1, 1, 1], [1, -1, -1]])
+    plan = BinaryConvPlan(64, 64, 3)
+    plan.ensure_program(K)
+    fresh = plan.compile()
+    other = BinaryConvPlan(64, 64, 3)
+    assert other.cycles == other.compile().n_cycles != fresh.n_cycles
+    other.ensure_program(K)
+    after = other.compile()
+    assert (fresh.n_cycles, fresh.stats) == (after.n_cycles, after.stats)
+    for field in TRACE_FIELDS:
+        np.testing.assert_array_equal(getattr(fresh, field),
+                                      getattr(after, field))
+    A = np.random.default_rng(3).choice([-1, 1], size=(64, 64))
+    out, cycles = plan.run(A, K)
+    assert cycles == fresh.n_cycles
+    assert np.array_equal(out, ref_binary_conv(A, K))
+
+
+def test_binary_conv_without_a_kernel_names_ensure_program():
+    with pytest.raises(RuntimeError, match="ensure_program"):
+        BinaryConvPlan(64, 64, 3).compile()
 
 
 @settings(max_examples=8, deadline=None)

@@ -313,6 +313,42 @@ def test_compile_metrics():
     assert metrics.counter("compile.seconds").value > 0
 
 
+def _bconv_plan():
+    from repro.core import BinaryConvPlan
+    return BinaryConvPlan(8, 16, 3, **GEOM)
+
+
+def _conv_plan():
+    from repro.core.conv import ConvPlan
+    return ConvPlan(8, 6, 3, 4, specialize_kernel=True, **GEOM)
+
+
+@pytest.mark.parametrize("make,op,k1,k2", [
+    (_bconv_plan, "binary_conv", [[1, 1, -1], [-1, 1, 1], [1, -1, -1]],
+     [[-1, -1, 1], [1, -1, -1], [-1, 1, 1]]),
+    (_conv_plan, "conv", [[1, 2, 3], [4, 5, 6], [7, 8, 9]],
+     [[0, 15, 0], [3, 0, 5], [0, 1, 0]]),
+])
+def test_plan_build_span_and_counter(tracer, make, op, k1, k2):
+    """One ``plan.build`` span and one ``plan.kernel_programs`` step per
+    distinct kernel a K-specialised plan is given; asking again for the
+    same kernel builds nothing."""
+    k1, k2 = np.array(k1), np.array(k2)
+    built = metrics.counter("plan.kernel_programs")
+    plan = make()
+    plan.ensure_program(k1)
+    assert built.value == 1
+    plan.ensure_program(k1.copy())
+    assert built.value == 1
+    plan.ensure_program(k2)
+    plan.ensure_program(k2)
+    assert built.value == 2
+    evs = [e for e in tracer.events() if e["name"] == "plan.build"]
+    assert len(evs) == 2
+    assert all(e["args"]["op"] == op for e in evs)
+    assert evs[-1]["args"]["cycles"] == plan.cycles == plan.compile().n_cycles
+
+
 def test_serve_cache_and_latency_metrics():
     rng = np.random.default_rng(0)
     svc = PlanService(**GEOM)

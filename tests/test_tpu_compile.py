@@ -12,8 +12,10 @@ Compiled, at the paper's 1024x1024 / 32-partition geometry:
 * the unfused body of ``MatvecPlan(1024, 8, 32)`` (Table I; 158 segments,
   so jax replays it unfused), and of ``MatvecPlan`` and ``ConvPlan(1024, 8,
   3, 32)`` (Table II) with no copy of the whole word in any per-cycle loop;
-* the per-word program around each of the three (32 crossbars in, pack,
-  replay, unpack, 32 out), with its scratch bytes bounded;
+* the per-word program around each of the three, and around the fused
+  body of ``BinaryConvPlan(1024, 256, 3)`` (Table II, eight 1,023-cycle
+  row scans), 32 crossbars in, pack, replay, unpack, 32 out, with its
+  scratch bytes bounded;
 * the word program of the matvec and the conv as a TPU runs it, with the
   replay kernel in place of the per-cycle loops (``core/replay_kernel.py``);
 * the three Pallas kernels at the operand shapes the pallas backend passes
@@ -32,7 +34,7 @@ import re
 import numpy as np
 import pytest
 
-from repro.core import BinaryMatvecPlan, MatvecPlan, have_jax
+from repro.core import BinaryConvPlan, BinaryMatvecPlan, MatvecPlan, have_jax
 from repro.core import pallas_exec as px
 from repro.core.conv import ConvPlan
 
@@ -157,22 +159,29 @@ def test_unfused_body_per_cycle_loops_hold_no_word_copy(one_chip, kind):
     assert any(copies for nested, copies in loops.values() if nested)
 
 
-@pytest.mark.parametrize("kind", ["binary_matvec", "matvec", "conv"])
+@pytest.mark.parametrize("kind", ["binary_matvec", "binary_conv", "matvec",
+                                  "conv"])
 def test_device_word_program_at_paper_geometry(one_chip, kind):
     """The one program a full word runs: 32 uint8 crossbars in (viewed as
-    uint32), packed, replayed (fused body for the binary matvec, unfused
-    body for the matvec and the conv) and unpacked, 32 crossbars out. Its
-    scratch stays under one uint32 copy of the block, so the pack's
-    shift-and-OR is fused and not materialised."""
+    uint32), packed, replayed (fused body for the binary matvec and the
+    binary conv, unfused body for the matvec and the conv) and unpacked, 32
+    crossbars out. Its scratch stays under one uint32 copy of the block, so
+    the pack's shift-and-OR is fused and not materialised."""
     import jax
     import jax.numpy as jnp
 
     from repro.core.engine import (WORD_BITS, device_word_program,
                                    jax_unfused_body)
-    from repro.core.fused import jax_fused_body
+    from repro.core.fused import jax_fuse_eligible, jax_fused_body
 
     if kind == "binary_matvec":
         cp = BinaryMatvecPlan(1024, 384, **GEOM).compile()
+        body = jax_fused_body(cp)
+    elif kind == "binary_conv":   # the Table II cell's kernel
+        plan = BinaryConvPlan(1024, 256, 3, **GEOM)
+        plan.ensure_program(np.array([[1, 1, -1], [-1, 1, 1], [1, -1, -1]]))
+        cp = plan.compile()
+        assert jax_fuse_eligible(cp)
         body = jax_fused_body(cp)
     else:
         cp = _unfused_program(kind)
