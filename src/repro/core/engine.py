@@ -602,7 +602,8 @@ def device_word_program(body, rows: int, cols: int):
     one program the crossbars are packed into the canonical word (bit b =
     crossbar b; the pad row, the pad column and bits ``>= P`` are zero),
     replayed by ``body`` and unpacked again, so the host neither packs nor
-    unpacks.
+    unpacks. :func:`replay_words` keeps two words of it on the device at a
+    time: one replaying while the host ships the next and fetches the last.
     """
     import jax
     import jax.numpy as jnp
@@ -686,74 +687,93 @@ def replay_words(mem: np.ndarray, run, call: Optional[int] = None,
     The host ships each word's crossbars as they are, viewed as uint32 (a
     partial word padded with zero crossbars to :func:`padded_width`, rows
     with zero bytes where ``C`` is not a multiple of 4), dispatches ``run``
-    and fetches its result, which :data:`COPY_THREADS` threads copy into
-    one preallocated ``(B, R, C)`` output (:func:`_output_like`: the last
-    call's memory where its caller has let go of it) while the next word
-    runs; packing
-    and unpacking happen on the device. One word is on the device at a
-    time, and at most two fetched results are held on the host.
+    and starts the result's copy to the host at once; packing and
+    unpacking happen on the device. The loop is two words deep: word
+    ``w + 1`` is shipped and dispatched before word ``w``'s result is
+    waited for, so the device replays one word while the host transfers
+    its neighbours. Two words are on the device at a time, and each
+    word's buffers are dropped once its host copy is taken. Each fetched
+    result goes to :data:`COPY_THREADS` threads that copy it into one
+    preallocated ``(B, R, C)`` output (:func:`_output_like`: the last
+    call's memory where its caller has let go of it) while the loop goes
+    on; at most two fetched results are held on the host.
     ``word_args(w)`` gives the further host arguments of ``run`` for word
     ``w`` (default: none). Spans, all tagged with the engine ``call`` id:
     per word ``engine.word`` (word, bytes shipped) with children
-    ``engine.h2d`` (arguments to the device), ``engine.replay`` (dispatch
-    of ``run``) and ``engine.d2h`` (wait for the result, fetch it, wait for
-    the previous word's copy and hand this one to the copy threads).
-    Counters: ``engine.device_pack.words`` and
+    ``engine.h2d`` (arguments to the device) and ``engine.replay``
+    (dispatch of ``run`` and of the copy back); then, after word
+    ``w + 1``'s ``engine.word``, word ``w``'s ``engine.d2h`` (wait for the
+    fetch, wait for the previous word's copy and hand this one to the
+    copy threads). Counters: ``engine.device_pack.words`` and
     ``engine.device_pack.padded_crossbars`` (``engine.device_pack.traces``
-    counts the word program's compiled widths); given the program's
-    ``modes`` (:func:`mode_cycles`), ``engine.replay.col_cycles``,
-    ``engine.replay.row_cycles`` and ``engine.replay.init_cycles`` grow by
-    its cycles of each mode for every word replayed; given the number of
-    same-mode ``runs`` the unfused body loops over (:func:`mode_runs`),
-    ``engine.replay.mode_runs`` grows by it for every word replayed; given
-    the ``live_gates`` the TPU's replay kernel replays
+    counts the word program's compiled widths);
+    ``engine.word_loop.overlapped_words`` grows by the words whose fetch
+    ran while the next word was on the device (words - 1 a call); given
+    the program's ``modes`` (:func:`mode_cycles`),
+    ``engine.replay.col_cycles``, ``engine.replay.row_cycles`` and
+    ``engine.replay.init_cycles`` grow by its cycles of each mode for
+    every word replayed; given the number of same-mode ``runs`` the
+    unfused body loops over (:func:`mode_runs`), ``engine.replay.mode_runs``
+    grows by it for every word replayed; given the ``live_gates`` the
+    TPU's replay kernel replays
     (:func:`repro.core.replay_kernel.replay_table`),
     ``engine.replay.live_gates`` grows by them for every word replayed.
     """
     from concurrent.futures import ThreadPoolExecutor
 
     import jax
-    import jax.numpy as jnp
 
     B, R, C = mem.shape
     C4 = -(-C // 4)
+    words = word_count(B)
     out = _output_like(mem)
     padded = 0
+
+    def dispatch(w):
+        """Word ``w`` shipped, replaying and on its way back: its result."""
+        nonlocal padded
+        lo = WORD_BITS * w
+        n = min(WORD_BITS, B - lo)
+        x = mem[lo:lo + n]
+        P = padded_width(n)
+        if P > n or 4 * C4 > C:
+            x = np.zeros((P, R, 4 * C4), np.uint8)
+            x[:n, :, :C] = mem[lo:lo + n]
+            padded += P - n
+        x = x.view(np.uint32)
+        with _span("engine.word", call=call, word=w, bytes=x.nbytes):
+            args = ((x,) if word_args is None
+                    else (x,) + tuple(word_args(w)))
+            with _span("engine.h2d", call=call, word=w):
+                args = jax.device_put(args)
+            with _span("engine.replay", call=call, word=w):
+                res = run(*args)
+                res.copy_to_host_async()
+        return res      # no operand outlives its word's dispatch
+
     copies: list = []
     with ThreadPoolExecutor(COPY_THREADS) as copier:
-        for w in range(word_count(B)):
+        ahead = dispatch(0)
+        for w in range(words):
+            res, ahead = ahead, (dispatch(w + 1) if w + 1 < words
+                                 else None)
             lo = WORD_BITS * w
             n = min(WORD_BITS, B - lo)
-            x = mem[lo:lo + n]
-            P = padded_width(n)
-            if P > n or 4 * C4 > C:
-                x = np.zeros((P, R, 4 * C4), np.uint8)
-                x[:n, :, :C] = mem[lo:lo + n]
-                padded += P - n
-            x = x.view(np.uint32)
-            with _span("engine.word", call=call, word=w, bytes=x.nbytes):
-                args = ((x,) if word_args is None
-                        else (x,) + tuple(word_args(w)))
-                with _span("engine.h2d", call=call, word=w):
-                    args = jax.tree_util.tree_map(jnp.asarray, args)
-                with _span("engine.replay", call=call, word=w):
-                    res = run(*args)
-                del args, x    # no device buffer outlives its word
-                with _span("engine.d2h", call=call, word=w):
-                    host = np.asarray(res).view(np.uint8)[:n, :, :C]
-                    for f in copies:
-                        f.result()
-                    cuts = np.linspace(0, n, min(COPY_THREADS, n) + 1,
-                                       dtype=int)
-                    copies = [copier.submit(np.copyto, out[lo + i:lo + j],
-                                            host[i:j])
-                              for i, j in zip(cuts[:-1], cuts[1:])]
-                del res, host
+            with _span("engine.d2h", call=call, word=w):
+                host = np.asarray(res).view(np.uint8)[:n, :, :C]
+                for f in copies:
+                    f.result()
+                cuts = np.linspace(0, n, min(COPY_THREADS, n) + 1,
+                                   dtype=int)
+                copies = [copier.submit(np.copyto, out[lo + i:lo + j],
+                                        host[i:j])
+                          for i, j in zip(cuts[:-1], cuts[1:])]
+            del res, host
         for f in copies:
             f.result()
-    words = word_count(B)
     _metrics.counter("engine.device_pack.words").inc(words)
     _metrics.counter("engine.device_pack.padded_crossbars").inc(padded)
+    _metrics.counter("engine.word_loop.overlapped_words").inc(words - 1)
     if modes is not None:
         for name, n in zip(("col", "row", "init"), modes):
             _metrics.counter(f"engine.replay.{name}_cycles").inc(n * words)

@@ -11,6 +11,9 @@ one jitted program packs them into the canonical word, replays and unpacks
   uint32;
 * at most six compiled widths (1, 2, 4, 8, 16, 32) for one program,
   whatever the batch;
+* the word loop's two-deep pipeline: word w+1 dispatched before word w is
+  fetched, never more than two words on the device, and
+  ``engine.word_loop.overlapped_words`` (words - 1 a call);
 * the ``engine.device_pack.*`` counters: words and padding crossbars on
   this path, nothing on the numpy and ``FaultModel`` paths; every width
   compiled by the served prewarm, none by the traffic after it;
@@ -38,7 +41,7 @@ from repro.obs import metrics
 
 pytestmark = pytest.mark.skipif(not have_jax(), reason="needs jax")
 
-BATCHES = (1, 5, 31, 32, 33, 64, 100)
+BATCHES = (1, 5, 31, 32, 33, 64, 96, 100)
 RUNNERS = ("fused", "realization", "unfused")
 
 
@@ -130,6 +133,66 @@ def test_padded_widths_compile_at_most_six_shapes(plan_cp):
     run = device_word_program(counted, plan.rows, plan.cols)
     replay_words(mems[:96], run)
     assert len(traced) == 1
+
+
+class _Recorded:
+    """A word program's result that logs when the word loop starts its copy
+    back and when it fetches it."""
+
+    def __init__(self, res, word, log):
+        self.res, self.word, self.log = res, word, log
+
+    def copy_to_host_async(self):
+        self.log.append(("copy", self.word))
+        self.res.copy_to_host_async()
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("fetch", self.word))
+        return np.asarray(self.res)
+
+
+@pytest.mark.parametrize("W", [1, 2, 3, 32])
+def test_word_loop_dispatches_the_next_word_before_fetching(plan_cp, W):
+    """A W-word call is two words deep: each word's copy back starts as it
+    is dispatched, word w+1 is dispatched before word w is fetched, never
+    more than two words are unfetched or held on the device, and
+    ``engine.word_loop.overlapped_words`` grows by W - 1; the bits are
+    numpy's."""
+    import weakref
+
+    from repro.core.fused import jax_fused_body
+
+    plan, cp = plan_cp
+    run = device_word_program(jax_fused_body(cp), plan.rows, plan.cols)
+    log, held, unfetched = [], set(), []
+
+    def recorded(*args):
+        w = sum(ev[0] == "dispatch" for ev in log)
+        fetched = sum(ev[0] == "fetch" for ev in log)
+        unfetched.append(w + 1 - fetched)
+        log.append(("dispatch", w, len(held)))
+        res = _Recorded(run(*args), w, log)
+        held.add(w)
+        weakref.finalize(res, held.discard, w)
+        return res
+
+    base = _mems(plan, WORD_BITS, seed=W)
+    want = np.concatenate([execute(cp, base, backend="numpy").mem] * W)
+    overlapped = metrics.counter("engine.word_loop.overlapped_words")
+    got = replay_words(np.concatenate([base] * W), recorded)
+    np.testing.assert_array_equal(got, want)
+    assert overlapped.value == W - 1
+
+    at = {ev[:2]: i for i, ev in enumerate(log)}
+    assert [ev[1] for ev in log if ev[0] == "fetch"] == list(range(W))
+    for w in range(W):
+        assert at["dispatch", w] < at["copy", w] < at["fetch", w]
+        if w + 1 < W:
+            assert at["copy", w] < at["dispatch", w + 1] < at["fetch", w]
+    assert max(unfetched) == min(W, 2)
+    # at each dispatch the words before the last are gone from the device
+    assert all(ev[2] <= 1 for ev in log if ev[0] == "dispatch")
+    assert held == set()
 
 
 @pytest.mark.parametrize("backend,faults", [

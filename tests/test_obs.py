@@ -237,11 +237,12 @@ def _batch_of_64():
 
 @pytest.mark.parametrize("runner", ["fused", "realization", "unfused"])
 def test_engine_host_path_spans_under_the_profiler(tmp_path, runner):
-    """Two packed words through each jax word loop: one engine.word per
-    word with its h2d/replay/d2h children, all inside engine.execute under
-    one call id, no host engine.pack or engine.unpack (the word program
-    packs and unpacks on the device), and the same bits as without the
-    profiler."""
+    """Two packed words through each jax word loop, pipelined: one
+    engine.word per word with its h2d/replay children, word 1 shipped and
+    dispatched before word 0's engine.d2h, then each word's engine.d2h in
+    order, all inside engine.execute under one call id, no host
+    engine.pack or engine.unpack (the word program packs and unpacks on the
+    device), and the same bits as without the profiler."""
     from repro.device.faults import FaultModel, FaultRealization
     plan, mems = _batch_of_64()
     cp = plan.compile()
@@ -262,8 +263,8 @@ def test_engine_host_path_spans_under_the_profiler(tmp_path, runner):
     names = [name for name, *_ in evs]
     assert "engine.pack" not in names and "engine.unpack" not in names
     assert names == (["engine.execute"]
-                     + ["engine.word", "engine.h2d", "engine.replay",
-                        "engine.d2h"] * 2)
+                     + ["engine.word", "engine.h2d", "engine.replay"] * 2
+                     + ["engine.d2h"] * 2)
     (_, x0, x1, ex), *inner = evs
     assert len({st["call"] for *_, st in evs}) == 1
     assert all(x0 <= s and e <= x1 for _, s, e, _ in inner)
@@ -272,8 +273,12 @@ def test_engine_host_path_spans_under_the_profiler(tmp_path, runner):
         _, w0, w1, st = inner[i]
         assert st == {"call": ex["call"], "word": w,
                       "bytes": 32 * plan.rows * plan.cols}
-        for _, s, e, child in inner[i + 1:i + 4]:
+        for _, s, e, child in inner[i + 1:i + 3]:
             assert w0 <= s and e <= w1 and child["word"] == w
+    fetches = [ev for ev in inner if ev[0] == "engine.d2h"]
+    assert [st["word"] for *_, st in fetches] == [0, 1]
+    # word 0's fetch waits until word 1 is on the device
+    assert fetches[0][1] >= inner[words[1]][2]
 
 
 def test_fault_model_runner_keeps_the_host_pack_spans(tmp_path):
